@@ -28,15 +28,16 @@ SNIPPET = r"""
 import json, time
 import jax, numpy as np
 from repro.configs import base
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.train.step import (TrainConfig, bucket_decisions, make_train_step,
                               make_init_fns)
 from repro.kernels.collectives import plan as kplan
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.train.data import DataConfig, make_batch
 from repro.launch import hlo, dryrun
 
-mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("qwen3-32b"))
 key = jax.random.key(0)
 params_shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), key)

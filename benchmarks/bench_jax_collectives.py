@@ -14,8 +14,9 @@ SNIPPET = """
 import time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((8,), ("x",))
 from repro.collectives import api
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 
 rng = np.random.RandomState(0)
 x = jnp.asarray(rng.randn(8, 1 << 16).astype(np.float32))

@@ -51,15 +51,16 @@ SHAPE = dict(n_ranks=8, n_replicas=2, tp=4)
 SNIPPET = r"""
 import json, time
 import jax, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base as cfgbase
 from repro.fleet import Fleet, FleetConfig, FleetEvent
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.serve.engine import ServeConfig, make_serve_fns, page_len
 from repro.serve.scheduler import poisson_trace
 
 N_REQ, RATE, MAX_NEW, PMIN, PMAX, SEED = 14, 1.0, 10, 4, 16, 0
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = cfgbase.reduced(cfgbase.get_config("gemma3-4b"))
 S = page_len(cfg, PMAX, MAX_NEW)
 scfg = ServeConfig(dp_axes=("data",), backend="auto")

@@ -39,8 +39,9 @@ try:  # package import (benchmarks.run) or cwd convention (standalone)
 except ImportError:
     from common import emit  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.configs import base as cfgbase  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.serve.engine import (ServeConfig, make_serve_fns,  # noqa: E402
                                 page_len)
@@ -153,7 +154,7 @@ def main(argv=None):
                          "gate it under 5% with unchanged trace counts")
     args = ap.parse_args(argv)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = cfgbase.reduced(cfgbase.get_config(args.arch))
     S = page_len(cfg, args.prompt_max, args.max_new)
 

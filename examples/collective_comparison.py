@@ -18,16 +18,17 @@ import numpy as np  # noqa: E402
 
 from repro.configs import base  # noqa: E402
 from repro.launch import hlo as H  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.train.data import DataConfig, make_batch  # noqa: E402
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.train.step import (TrainConfig, make_init_fns,  # noqa: E402
                               make_train_step)
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = base.reduced(base.get_config("phi4-mini-3.8b"))
     key = jax.random.key(0)
     shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), key)

@@ -13,17 +13,18 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import base  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.train.data import DataConfig, make_batch  # noqa: E402
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.train.step import (TrainConfig, make_init_fns,  # noqa: E402
                               make_train_step)
 
 
 def main():
     # 2 "pods" x 2-way data parallel x 2-way model parallel
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = base.reduced(base.get_config("phi4-mini-3.8b"))
     tcfg = TrainConfig(
         backend="bine",                      # the paper's collectives

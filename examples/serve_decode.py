@@ -15,8 +15,9 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.configs import base  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.serve.engine import (ServeConfig, make_serve_fns,  # noqa: E402
                                 page_len)
@@ -35,7 +36,7 @@ def main():
     ap.add_argument("--prompt-max", type=int, default=40)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = base.reduced(base.get_config(args.arch))
     S = page_len(cfg, args.prompt_max, args.max_new)
     fns = make_serve_fns(cfg, ServeConfig(dp_axes=("data",)), mesh,

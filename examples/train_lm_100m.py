@@ -19,11 +19,12 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import base  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.train import checkpoint as ckpt  # noqa: E402
 from repro.train.data import DataConfig, Prefetcher  # noqa: E402
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.train.step import (TrainConfig, make_init_fns,  # noqa: E402
                               make_train_step)
 
@@ -36,7 +37,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_100m_ckpt")
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     cfg = base.get_config("xlstm-125m")          # FULL assigned config
     tcfg = TrainConfig(
         backend="bine", dp_axes=("data",),

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.collectives import compression as comp
 from repro.core import tables as tb
 from repro.core.schedules import BLOCK_ALL, KIND_REDUCE, Schedule
@@ -36,8 +35,8 @@ Axis = Union[str, Tuple[str, ...]]
 
 def axis_size(axis: Axis) -> int:
     if isinstance(axis, (tuple, list)):
-        return int(np.prod([compat.axis_size(a) for a in axis]))
-    return compat.axis_size(axis)
+        return int(np.prod([lax.axis_size(a) for a in axis]))
+    return lax.axis_size(axis)
 
 
 #: stack of {axis name -> traced index} pushed by ``axis_index_hints``
@@ -48,10 +47,9 @@ _INDEX_HINTS: list = []
 def axis_index_hints(hints):
     """Supply per-axis rank indices as *data* instead of ``lax.axis_index``.
 
-    Under partial-auto shard_map on jax 0.4.x, ``lax.axis_index`` of a
-    manual axis lowers to a PartitionId instruction the SPMD partitioner
-    rejects (and new-jax Shardy rejects it in nested manual regions).  The
-    caller passes each manual axis an ``arange`` sharded over that axis and
+    Shardy rejects ``lax.axis_index`` inside a nested manual region (the
+    MoE expert-parallel ``shard_map`` inside the train step).  The caller
+    passes each manual axis an ``arange`` sharded over that axis and
     registers the per-shard element here; every collective in this module
     then picks up the hint transparently.
     """
@@ -68,7 +66,7 @@ def axis_index(axis: Axis):
         # axis_size and the schedule tables
         idx = axis_index(axis[0])
         for a in axis[1:]:
-            idx = idx * compat.axis_size(a) + axis_index(a)
+            idx = idx * lax.axis_size(a) + axis_index(a)
         return idx
     for hints in reversed(_INDEX_HINTS):
         if axis in hints:
@@ -79,6 +77,19 @@ def axis_index(axis: Axis):
 def _flatten(x):
     shape, dtype = x.shape, x.dtype
     return x.reshape(-1), (shape, dtype)
+
+
+def permute_blocks(x, order, dim: int = 0):
+    """Split ``x`` along ``dim`` into ``len(order)`` equal blocks and
+    concatenate them in the static ``order``.
+
+    Static slices, not a gather: on TPU a gather with constant row
+    indices compiles in time that grows with the payload (25 s for a
+    614 M-element gradient bucket), while slices stay flat."""
+    blk = x.shape[dim] // len(order)
+    return jnp.concatenate(
+        [lax.slice_in_dim(x, int(b) * blk, (int(b) + 1) * blk, axis=dim)
+         for b in order], axis=dim)
 
 
 def _pad_to(v, mult: int):
@@ -171,9 +182,7 @@ def reduce_scatter(x, axis: Axis, algo: str = "bine"):
     bt = tb.butterfly_tables(_KIND[algo], p)
     v = x.reshape(-1)
     assert v.shape[0] % p == 0, "reduce_scatter needs len divisible by p"
-    blk = v.shape[0] // p
-    v = v.reshape(p, blk)[jnp.asarray(bt.inv_final)].reshape(-1)
-    return _rs_core(v, axis, bt)
+    return _rs_core(permute_blocks(v, bt.inv_final), axis, bt)
 
 
 def allgather(x, axis: Axis, algo: str = "bine"):
@@ -184,10 +193,7 @@ def allgather(x, axis: Axis, algo: str = "bine"):
     if algo == "ring":
         return _ring_allgather(x, axis)
     bt = tb.butterfly_tables(_KIND[algo], p)
-    v = x.reshape(-1)
-    blk = v.shape[0]
-    v = _ag_core(v, axis, bt)
-    return v.reshape(p, blk)[jnp.asarray(bt.final_block)].reshape(-1)
+    return permute_blocks(_ag_core(x.reshape(-1), axis, bt), bt.final_block)
 
 
 # ---------------------------------------------------------------------------
@@ -253,9 +259,7 @@ def reduce_scatter_q(x, axis: Axis, algo: str = "bine"):
         raise ValueError(f"int8 wire supports bine/recdoub, not {algo!r}")
     bt = tb.butterfly_tables(_KIND[algo], p)
     assert v.shape[0] % p == 0, "reduce_scatter needs len divisible by p"
-    blk = v.shape[0] // p
-    v = v.reshape(p, blk)[jnp.asarray(bt.inv_final)].reshape(-1)
-    return _rs_core_q(v, axis, bt)
+    return _rs_core_q(permute_blocks(v, bt.inv_final), axis, bt)
 
 
 def allgather_q(x, axis: Axis, algo: str = "bine"):
@@ -272,14 +276,10 @@ def allgather_q(x, axis: Axis, algo: str = "bine"):
     if algo not in _KIND:
         raise ValueError(f"int8 wire supports bine/recdoub, not {algo!r}")
     bt = tb.butterfly_tables(_KIND[algo], p)
-    blk = v.shape[0]
     q, s = comp.quantize_wire(v)
     q, s = _ag_core_q(q, s, axis, bt)
-    ch = comp.wire_chunk(blk)
-    fb = jnp.asarray(bt.final_block)
-    q = q.reshape(p, blk)[fb].reshape(-1)
-    s = s.reshape(p, blk // ch)[fb].reshape(-1)
-    return comp.dequantize_wire(q, s)
+    return comp.dequantize_wire(permute_blocks(q, bt.final_block),
+                                permute_blocks(s, bt.final_block))
 
 
 # ---------------------------------------------------------------------------
@@ -324,12 +324,8 @@ def reduce_scatter_dim(x, dim: int, axis: Axis, algo: str = "bine"):
         return _ring_rs_dim(x, dim, axis)
     bt = tb.butterfly_tables(_KIND[algo], p)
     assert x.shape[dim] % p == 0, (x.shape, dim, p)
-    blk = x.shape[dim] // p
     # pre-permute blocks along dim by inv_final so rank r ends with block r
-    parts = [lax.slice_in_dim(x, int(b) * blk, (int(b) + 1) * blk, axis=dim)
-             for b in bt.inv_final]
-    x = jnp.concatenate(parts, axis=dim)
-    return _rs_core_dim(x, dim, axis, bt)
+    return _rs_core_dim(permute_blocks(x, bt.inv_final, dim), dim, axis, bt)
 
 
 def allgather_dim(x, dim: int, axis: Axis, algo: str = "bine"):
@@ -340,11 +336,8 @@ def allgather_dim(x, dim: int, axis: Axis, algo: str = "bine"):
     if algo == "ring":
         return _ring_ag_dim(x, dim, axis)
     bt = tb.butterfly_tables(_KIND[algo], p)
-    blk = x.shape[dim]
-    v = _ag_core_dim(x, dim, axis, bt)
-    parts = [lax.slice_in_dim(v, int(b) * blk, (int(b) + 1) * blk, axis=dim)
-             for b in bt.final_block]
-    return jnp.concatenate(parts, axis=dim)
+    return permute_blocks(_ag_core_dim(x, dim, axis, bt), bt.final_block,
+                          dim)
 
 
 def _ring_rs_dim(x, dim: int, axis: Axis):
@@ -501,7 +494,7 @@ def gather(x, axis: Axis, root: int = 0, algo: str = "bine"):
         is_r = jnp.asarray(gt.recv_mask[j])[idx]
         buf = lax.dynamic_update_slice(
             buf, jnp.where(is_r, recv, cur), (off,))
-    return buf.reshape(p, blk)[jnp.asarray(gt.root_unrot)].reshape(-1)
+    return permute_blocks(buf, gt.root_unrot)
 
 
 def scatter(x, axis: Axis, root: int = 0, algo: str = "bine"):
@@ -519,7 +512,7 @@ def scatter(x, axis: Axis, root: int = 0, algo: str = "bine"):
     v = x.reshape(-1)
     assert v.shape[0] % p == 0
     blk = v.shape[0] // p
-    buf = v.reshape(p, blk)[jnp.asarray(st.root_rot)].reshape(-1)
+    buf = permute_blocks(v, st.root_rot)
     for j in range(st.s):
         sz = st.sizes[j] * blk
         soff = jnp.asarray(st.send_off[j])[idx] * blk

@@ -133,14 +133,15 @@ def register(cfg: ModelConfig) -> ModelConfig:
 
 
 def get_config(name: str) -> ModelConfig:
-    if not _REGISTRY:
+    # load on a miss, not only when empty: a caller that imported one
+    # config module directly has registered just that one
+    if name not in _REGISTRY:
         _load_all()
     return _REGISTRY[name]
 
 
 def list_configs():
-    if not _REGISTRY:
-        _load_all()
+    _load_all()
     return sorted(_REGISTRY)
 
 

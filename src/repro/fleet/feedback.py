@@ -18,7 +18,7 @@ File format::
     {
       "format": 1,
       "device_kind": "cpu", "topology": "lumi", "p": 8,
-      "provenance": {"timestamp": null, "jax": "0.4.37",
+      "provenance": {"timestamp": null, "jax": "0.9.0",
                      "platform": "cpu", "source": "launch.fleet"},
       "replicas": {
         "0": {"ticks": 128, "ewma_tick_s": 1.9e-3,

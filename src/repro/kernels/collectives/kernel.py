@@ -28,8 +28,9 @@ streams in while chunk ``i`` reduces.  Arithmetic order is identical to
 the unfused shmap path (``kept + recv``), which is what makes the
 ``pallas_fused`` backend bit-for-bit with the shmap backend in fp32.
 
-Validated in interpret mode against ``ref.py``
-(tests/kernels/test_fused_collectives.py), following the
+Validated against ``ref.py`` in interpret mode
+(tests/kernels/test_fused_collectives.py), compiled for a v5e chip at
+real bucket widths (tests/kernels/test_tpu_compile.py), following the
 ``kernels/flash_attention`` pattern.
 """
 
@@ -42,8 +43,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: default chunk cap (elements) for the 1-D step kernels
+#: default chunk cap (elements) for the 1-D step kernels; on the chip a
+#: 1-D block must be whole 1024-element tiles, so compiled callers pass
+#: payloads that are multiples of it (``ops`` runs other rank blocks on the
+#: shmap path)
 CHUNK = 1024
+
+#: row cap of the int8 RS kernel's 2-D ``(rows, WIRE_CHUNK)`` blocks; on
+#: the chip the int8 operand needs whole 32-row tiles
+Q_ROWS = 128
+
+#: lanes of the AG kernel's ``(rows, LANES)`` view of a flat payload, and
+#: its row cap per block (512 rows: 256 KiB of f32)
+LANES = 128
+AG_ROWS = 512
 
 
 def _pow2_divisor(n: int, cap: int = CHUNK) -> int:
@@ -54,194 +67,165 @@ def _pow2_divisor(n: int, cap: int = CHUNK) -> int:
     return c
 
 
+def _out(shape, dtype, like):
+    """Output ``ShapeDtypeStruct`` varying over the same manual axes as
+    ``like``, so the kernels type-check under ``shard_map(check_vma=True)``."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
+
+
 # ---------------------------------------------------------------------------
 # Reduce-scatter step: fused keep-slice + reduce (+ next-step send pack)
 # ---------------------------------------------------------------------------
 
-def _rs_step_body_send(cs_ref, buf_ref, recv_ref, out_ref, send_ref, *,
-                       chunk, q):
-    j = pl.program_id(0)
+def _rs_step_body(cs_ref, buf_ref, recv_ref, out_ref, send_ref=None, *,
+                  nsend=0):
     s = buf_ref[...] + recv_ref[...]
     out_ref[...] = s
-    w0 = (1 - cs_ref[1]) * q
-    base = j * chunk
+    if send_ref is not None:
+        w0 = (1 - cs_ref[1]) * nsend
+        j = pl.program_id(0)
 
-    @pl.when(jnp.logical_and(base >= w0, base < w0 + q))
-    def _():
-        send_ref[pl.ds(base - w0, chunk)] = s
-
-
-def _rs_step_body_nosend(cs_ref, buf_ref, recv_ref, out_ref):
-    out_ref[...] = buf_ref[...] + recv_ref[...]
+        @pl.when(jnp.logical_and(j >= w0, j < w0 + nsend))
+        def _():
+            send_ref[...] = s
 
 
-def rs_step_kernel(buf, recv, c, c_next=None, *, interpret: bool = True):
+def _send_map(nsend):
+    """Block index of the outgoing half for grid step ``j``: the window's
+    own block inside it, clamped to the window's edge outside it — so the
+    block stays resident (no write-back) until the window has filled it."""
+    def index(j, cs):
+        return (jnp.clip(j - (1 - cs[1]) * nsend, 0, nsend - 1),)
+    return index
+
+
+def rs_step_kernel(buf, recv, c, c_next=None, *, interpret=False):
     """buf: [2h]; recv: [h] -> newbuf [h] (+ send [h//2] when c_next given).
 
     ``newbuf = buf[c*h : (c+1)*h] + recv``; the kept half is read directly
     at its dynamic offset through the scalar-prefetched block index map —
     no separate slice op ever materializes.  ``send`` is
-    ``newbuf[(1-c_next)*q : +q]``, packed in the same pass.
+    ``newbuf[(1-c_next)*q : +q]``, written block by block in the same pass.
     """
     h = recv.shape[0]
     assert buf.shape == (2 * h,), (buf.shape, h)
     if c_next is None:
         chunk = _pow2_divisor(h)
-        nch = h // chunk
         cs = jnp.stack([jnp.asarray(c, jnp.int32)])
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(nch,),
-            in_specs=[
-                pl.BlockSpec((chunk,), lambda j, cs: (cs[0] * nch + j,)),
-                pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-            ],
-            out_specs=pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-        )
-        return pl.pallas_call(
-            _rs_step_body_nosend, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((h,), buf.dtype),
-            interpret=interpret,
-        )(cs, buf, recv)
-
-    assert h % 2 == 0, h
-    q = h // 2
-    chunk = _pow2_divisor(q)
+    else:
+        assert h % 2 == 0, h
+        chunk = _pow2_divisor(h // 2)
+        cs = jnp.stack([jnp.asarray(c, jnp.int32),
+                        jnp.asarray(c_next, jnp.int32)])
     nch = h // chunk
-    cs = jnp.stack([jnp.asarray(c, jnp.int32),
-                    jnp.asarray(c_next, jnp.int32)])
+    block = pl.BlockSpec((chunk,), lambda j, cs: (j,))
+    out_specs = [block]
+    out_shape = [_out((h,), buf.dtype, buf)]
+    if c_next is not None:
+        out_specs.append(pl.BlockSpec((chunk,), _send_map(nch // 2)))
+        out_shape.append(_out((h // 2,), buf.dtype, buf))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(nch,),
         in_specs=[
             pl.BlockSpec((chunk,), lambda j, cs: (cs[0] * nch + j,)),
-            pl.BlockSpec((chunk,), lambda j, cs: (j,)),
+            block,
         ],
-        out_specs=[
-            pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-            # the send half stays resident for the whole grid; window
-            # chunks stream into it as they are reduced
-            pl.BlockSpec((q,), lambda j, cs: (0,)),
-        ],
+        out_specs=out_specs,
     )
-    return pl.pallas_call(
-        functools.partial(_rs_step_body_send, chunk=chunk, q=q),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((h,), buf.dtype),
-                   jax.ShapeDtypeStruct((q,), buf.dtype)],
-        interpret=interpret,
+    out = pl.pallas_call(
+        functools.partial(_rs_step_body, nsend=nch // 2),
+        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
     )(cs, buf, recv)
+    return out[0] if c_next is None else tuple(out)
 
 
 # ---------------------------------------------------------------------------
 # int8-wire reduce-scatter step: dequantize + reduce + re-quantize, one pass
 # ---------------------------------------------------------------------------
 
-def _rs_step_q_body_send(cs_ref, buf_ref, rq_ref, rs_ref, out_ref, sq_ref,
-                         ss_ref, *, chunk, w, ch_r, ch_s):
-    j = pl.program_id(0)
-    deq = (rq_ref[...].astype(jnp.float32).reshape(chunk // ch_r, ch_r)
-           * rs_ref[...][:, None]).reshape(chunk)
-    v = buf_ref[...] + deq
+def _rs_step_q_body(cs_ref, buf_ref, rq_ref, rs_ref, out_ref, sq_ref=None,
+                    ss_ref=None, *, nsend=0):
+    # rows are codec chunks: [R, ch] values, [R, 1] pow2 scales
+    v = buf_ref[...] + rq_ref[...].astype(jnp.float32) * rs_ref[...]
     out_ref[...] = v
-    w0 = (1 - cs_ref[1]) * w
-    base = j * chunk
+    if sq_ref is not None:
+        w0 = (1 - cs_ref[1]) * nsend
+        j = pl.program_id(0)
 
-    @pl.when(jnp.logical_and(base >= w0, base < w0 + w))
-    def _():
-        from repro.collectives.compression import pow2_scale
-        m = v.reshape(chunk // ch_s, ch_s)
-        scale = pow2_scale(jnp.max(jnp.abs(m), axis=1) / 127.0)
-        q = jnp.clip(jnp.round(m / scale[:, None]), -127,
-                     127).astype(jnp.int8)
-        sq_ref[pl.ds(base - w0, chunk)] = q.reshape(chunk)
-        ss_ref[pl.ds((base - w0) // ch_s, chunk // ch_s)] = scale
-
-
-def _rs_step_q_body_nosend(cs_ref, buf_ref, rq_ref, rs_ref, out_ref, *,
-                           chunk, ch_r):
-    deq = (rq_ref[...].astype(jnp.float32).reshape(chunk // ch_r, ch_r)
-           * rs_ref[...][:, None]).reshape(chunk)
-    out_ref[...] = buf_ref[...] + deq
+        @pl.when(jnp.logical_and(j >= w0, j < w0 + nsend))
+        def _():
+            from repro.collectives.compression import pow2_scale
+            scale = pow2_scale(jnp.max(jnp.abs(v), axis=1, keepdims=True)
+                               / 127.0)
+            sq_ref[...] = jnp.clip(jnp.round(v / scale), -127,
+                                   127).astype(jnp.int8)
+            ss_ref[...] = scale
 
 
 def rs_step_kernel_q(buf, recv_q, recv_s, c, c_next=None, *,
-                     interpret: bool = True):
+                     interpret=False):
     """int8-wire twin of :func:`rs_step_kernel` (oracle:
     ``ref.rs_step_ref_q``).
 
     ``buf``: [2h] float32; ``recv_q``: [h] int8; ``recv_s``: [h // ch]
     float32 per-chunk scales (``ch = compression.wire_chunk(h)``).  Each
-    grid block dequantizes its slice of the received payload, accumulates
+    grid block dequantizes its rows of the received payload, accumulates
     against the kept half in float32, and — with ``c_next`` given —
-    re-quantizes its slice of the next outgoing half (per-codec-chunk
+    re-quantizes its rows of the next outgoing half (per-codec-chunk
     scales computed in-block) in the same HBM pass: int8 stays on the
     wire, f32 only ever lives in the accumulation.
 
-    The codec chunk must divide the grid chunk so scales stay blockwise:
-    the send variant requires ``h % 512 == 0`` (callers fall back to the
-    shmap int8 path — bit-identical by construction — when the payload is
-    not 256-aligned per rank block).
+    The kernel works on a 2-D view with one codec chunk per row, so every
+    scale is its row's ``[R, 1]`` entry and no reshape happens in-kernel.
+    The send variant requires ``h % 512 == 0`` (both halves then chunk at
+    256); compiled on the chip, rows come in whole 32-row tiles
+    (``h % 8192 == 0``; ``ops`` runs other rank blocks on the shmap path).
     """
     from repro.collectives import compression as comp
 
     h = recv_q.shape[0]
     assert buf.shape == (2 * h,), (buf.shape, h)
-    ch_r = comp.wire_chunk(h)
-    assert recv_s.shape == (h // ch_r,), (recv_s.shape, h, ch_r)
+    ch = comp.wire_chunk(h)
+    assert recv_s.shape == (h // ch,), (recv_s.shape, h, ch)
+    rows = h // ch
     if c_next is None:
-        chunk = _pow2_divisor(h)
-        assert chunk % ch_r == 0, (chunk, ch_r)
-        nch = h // chunk
+        rb = _pow2_divisor(rows, Q_ROWS)
         cs = jnp.stack([jnp.asarray(c, jnp.int32)])
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(nch,),
-            in_specs=[
-                pl.BlockSpec((chunk,), lambda j, cs: (cs[0] * nch + j,)),
-                pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-                pl.BlockSpec((chunk // ch_r,), lambda j, cs: (j,)),
-            ],
-            out_specs=pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-        )
-        return pl.pallas_call(
-            functools.partial(_rs_step_q_body_nosend, chunk=chunk,
-                              ch_r=ch_r),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((h,), jnp.float32),
-            interpret=interpret,
-        )(cs, buf, recv_q, recv_s)
-
-    assert h % 512 == 0, (
-        f"rs_step_kernel_q send variant needs h % 512 == 0, got {h}")
-    w = h // 2
-    ch_s = comp.wire_chunk(w)
-    chunk = _pow2_divisor(w)
-    assert chunk % ch_r == 0 and chunk % ch_s == 0, (chunk, ch_r, ch_s)
-    nch = h // chunk
-    cs = jnp.stack([jnp.asarray(c, jnp.int32),
-                    jnp.asarray(c_next, jnp.int32)])
+    else:
+        assert h % 512 == 0, (
+            f"rs_step_kernel_q send variant needs h % 512 == 0, got {h}")
+        rb = _pow2_divisor(rows // 2, Q_ROWS)
+        cs = jnp.stack([jnp.asarray(c, jnp.int32),
+                        jnp.asarray(c_next, jnp.int32)])
+    nch = rows // rb
+    rowblock = pl.BlockSpec((rb, ch), lambda j, cs: (j, 0))
+    scaleblock = pl.BlockSpec((rb, 1), lambda j, cs: (j, 0))
+    out_specs = [rowblock]
+    out_shape = [_out((rows, ch), jnp.float32, buf)]
+    if c_next is not None:
+        send = _send_map(nch // 2)
+        out_specs += [pl.BlockSpec((rb, ch), lambda j, cs: send(j, cs) + (0,)),
+                      pl.BlockSpec((rb, 1), lambda j, cs: send(j, cs) + (0,))]
+        out_shape += [_out((rows // 2, ch), jnp.int8, buf),
+                      _out((rows // 2, 1), jnp.float32, buf)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(nch,),
         in_specs=[
-            pl.BlockSpec((chunk,), lambda j, cs: (cs[0] * nch + j,)),
-            pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-            pl.BlockSpec((chunk // ch_r,), lambda j, cs: (j,)),
+            pl.BlockSpec((rb, ch), lambda j, cs: (cs[0] * nch + j, 0)),
+            rowblock,
+            scaleblock,
         ],
-        out_specs=[
-            pl.BlockSpec((chunk,), lambda j, cs: (j,)),
-            # the outgoing (q, scales) pair stays resident for the whole
-            # grid; window chunks stream into it as they are re-quantized
-            pl.BlockSpec((w,), lambda j, cs: (0,)),
-            pl.BlockSpec((w // ch_s,), lambda j, cs: (0,)),
-        ],
+        out_specs=out_specs,
     )
-    return pl.pallas_call(
-        functools.partial(_rs_step_q_body_send, chunk=chunk, w=w,
-                          ch_r=ch_r, ch_s=ch_s),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((h,), jnp.float32),
-                   jax.ShapeDtypeStruct((w,), jnp.int8),
-                   jax.ShapeDtypeStruct((w // ch_s,), jnp.float32)],
-        interpret=interpret,
-    )(cs, buf, recv_q, recv_s)
+    out = pl.pallas_call(
+        functools.partial(_rs_step_q_body, nsend=nch // 2),
+        grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+    )(cs, buf.reshape(2 * rows, ch), recv_q.reshape(rows, ch),
+      recv_s.reshape(rows, 1))
+    if c_next is None:
+        return out[0].reshape(h)
+    newbuf, q, sc = out
+    return newbuf.reshape(h), q.reshape(h // 2), sc.reshape(rows // 2)
 
 
 # ---------------------------------------------------------------------------
@@ -249,37 +233,52 @@ def rs_step_kernel_q(buf, recv_q, recv_s, c, c_next=None, *,
 # ---------------------------------------------------------------------------
 
 def _ag_step_body(cs_ref, buf_ref, recv_ref, out_ref, *, nch):
+    # block copies only: no arithmetic on packed (bf16/int8) vregs
     j = pl.program_id(0)
     c = cs_ref[0]
     use_buf = jnp.logical_and(j >= c * nch, j < (c + 1) * nch)
-    out_ref[...] = jnp.where(use_buf, buf_ref[...], recv_ref[...])
+
+    @pl.when(use_buf)
+    def _():
+        out_ref[...] = buf_ref[...]
+
+    @pl.when(jnp.logical_not(use_buf))
+    def _():
+        out_ref[...] = recv_ref[...]
 
 
-def ag_step_kernel(buf, recv, c, *, interpret: bool = True):
+def ag_step_kernel(buf, recv, c, *, interpret=False):
     """buf, recv: [h] -> merged [2h] = [buf, recv] if c == 0 else
-    [recv, buf], written in one placement pass (no concat temporaries)."""
+    [recv, buf], written in one placement pass (no concat temporaries).
+
+    Any dtype, ``h % 128 == 0``.  The kernel works on the payload's
+    ``(h // 128, 128)`` row view, which on the chip is the flat vector's
+    own tiled layout when ``h`` is whole tiles (1024 32-bit, 2048 16-bit,
+    4096 8-bit elements), so the view costs no copy."""
     h = buf.shape[0]
-    assert recv.shape == (h,), (buf.shape, recv.shape)
-    chunk = _pow2_divisor(h)
-    nch = h // chunk
+    assert recv.shape == (h,) and h % LANES == 0, (buf.shape, recv.shape)
+    rows = h // LANES
+    rb = _pow2_divisor(rows, AG_ROWS)
+    nch = rows // rb
     cs = jnp.stack([jnp.asarray(c, jnp.int32)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(2 * nch,),
         in_specs=[
-            pl.BlockSpec((chunk,),
+            pl.BlockSpec((rb, LANES),
                          lambda j, cs: (jnp.clip(j - cs[0] * nch, 0,
-                                                 nch - 1),)),
-            pl.BlockSpec((chunk,),
+                                                 nch - 1), 0)),
+            pl.BlockSpec((rb, LANES),
                          lambda j, cs: (jnp.clip(j - (1 - cs[0]) * nch, 0,
-                                                 nch - 1),)),
+                                                 nch - 1), 0)),
         ],
-        out_specs=pl.BlockSpec((chunk,), lambda j, cs: (j,)),
+        out_specs=pl.BlockSpec((rb, LANES), lambda j, cs: (j, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ag_step_body, nch=nch), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((2 * h,), buf.dtype),
+        out_shape=_out((2 * rows, LANES), buf.dtype, buf),
         interpret=interpret,
-    )(cs, buf, recv)
+    )(cs, buf.reshape(rows, LANES), recv.reshape(rows, LANES))
+    return out.reshape(2 * h)
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +298,7 @@ def _ring_write_body(s_ref, v_ref, recv_ref, out_ref):
 
 def ring_update_kernel(v, recv, ridx, *, accumulate: bool = True,
                        return_updated: bool = False,
-                       interpret: bool = True):
+                       interpret=False):
     """v: [p*b]; recv: [b] -> v with block ``ridx`` ``+= recv`` (or
     ``= recv``).  The output aliases ``v``: only block ``ridx``'s chunks
     are revised, the other p-1 blocks never cross HBM.
@@ -326,7 +325,7 @@ def ring_update_kernel(v, recv, ridx, *, accumulate: bool = True,
             out_specs=v_out_spec)
         return pl.pallas_call(
             _ring_write_body, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
+            out_shape=_out(v.shape, v.dtype, v),
             input_output_aliases={1: 0}, interpret=interpret,
         )(s, v, recv)
     if not return_updated:
@@ -335,7 +334,7 @@ def ring_update_kernel(v, recv, ridx, *, accumulate: bool = True,
             out_specs=v_out_spec)
         return pl.pallas_call(
             _ring_update_body, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
+            out_shape=_out(v.shape, v.dtype, v),
             input_output_aliases={1: 0}, interpret=interpret,
         )(s, v, recv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -345,8 +344,7 @@ def ring_update_kernel(v, recv, ridx, *, accumulate: bool = True,
     )
     return pl.pallas_call(
         _ring_update_body, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct((b,), v.dtype)],
+        out_shape=[_out(v.shape, v.dtype, v), _out((b,), v.dtype, v)],
         input_output_aliases={1: 0}, interpret=interpret,
     )(s, v, recv)
 
@@ -404,13 +402,13 @@ def _mm_call(x, w, perm, lhs_perm: bool, *, bm, bn, bk, interpret):
     out_dtype = jnp.result_type(x, w)
     return pl.pallas_call(
         functools.partial(_mm_body, nk=nk), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=_out((m, n), out_dtype, x),
         interpret=interpret,
     )(jnp.asarray(perm, jnp.int32), x, w)
 
 
 def matmul_pack_kernel(x, w, block_perm, *, bm: int = 128, bn: int = 128,
-                       bk: int = 512, interpret: bool = True):
+                       bk: int = 512, interpret=False):
     """Tiled ``x @ w`` whose output row-block ``b`` holds input row-block
     ``block_perm[b]``: the reduce-scatter pre-permute lands for free in the
     matmul's output writes.  ``m % len(block_perm) == 0``."""
@@ -424,7 +422,7 @@ def matmul_pack_kernel(x, w, block_perm, *, bm: int = 128, bn: int = 128,
 
 
 def gather_matmul_kernel(xg, w, block_perm, *, bm: int = 128, bn: int = 128,
-                         bk: int = 512, interpret: bool = True):
+                         bk: int = 512, interpret=False):
     """Tiled ``xg[block_perm] @ w`` (row blocks): the allgather's final
     un-permute is folded into the LHS reads, never materialized."""
     m = xg.shape[0]

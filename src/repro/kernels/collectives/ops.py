@@ -20,9 +20,14 @@ kernel from ``kernel.py`` instead of a slice/add/concat HLO chain:
     index map), overlapping the matmul with the first/last exchange.
 
 Arithmetic order matches shmap exactly (``kept + recv``), so the fp32
-results are bit-for-bit identical to the shmap backend.  ``interpret``
-defaults to True off-TPU (the flash_attention convention), which keeps
-tier-1 green on the CPU host while the same code compiles on TPU.
+results are bit-for-bit identical to the shmap backend.  The kernels
+need every rank block to be whole tiles (:func:`block_align`); a payload
+whose rank block is not runs the shmap schedule instead
+(:func:`shmap_fallback`) — the same bits and the same wire bytes, where
+zero-padding would move up to 8x (int8: 32x) the payload's bytes,
+which neither the ``auto`` selector nor the link-byte counters price.
+``interpret=None`` resolves through :func:`default_interpret` —
+interpreted off-TPU (CPU tier-1), compiled on TPU.
 """
 
 from __future__ import annotations
@@ -46,12 +51,31 @@ ALGOS = ("bine", "recdoub", "ring")
 
 
 def default_interpret() -> bool:
-    """Interpret Pallas off-TPU (CPU tier-1), compile on TPU."""
+    """How the kernels run when the caller does not say: compiled on TPU,
+    interpreted elsewhere.  Interpreted kernels need a ``shard_map`` with
+    ``check_vma=False``: Pallas' interpreter evaluates the scalar-prefetch
+    block index maps without the values' varying-axes types."""
     return jax.default_backend() != "tpu"
 
 
 def _interp(interpret):
     return default_interpret() if interpret is None else interpret
+
+
+def block_align(dtype, wire: str = "plain") -> int:
+    """Per-rank block alignment (elements) the compiled step kernels
+    need: whole tiles for plain payloads (1024 elements of a 32-bit
+    dtype, 2048 of a 16-bit, 4096 of an 8-bit one); whole 32-row tiles
+    of the int8 RS kernel's codec-chunk rows for the int8 wire."""
+    if wire == "int8":
+        return comp.WIRE_CHUNK * 32
+    return K.CHUNK * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def shmap_fallback(blk: int, dtype, wire: str = "plain") -> bool:
+    """True when a rank block of ``blk`` elements is not whole kernel
+    tiles, so the fused entry runs the (bit-identical) shmap schedule."""
+    return blk % block_align(dtype, wire) != 0
 
 
 # ---------------------------------------------------------------------------
@@ -100,8 +124,7 @@ def _rs_core_fused_q(buf, axis: Axis, bt: tb.ButterflyTables, interpret):
     send = lax.dynamic_slice(buf, ((1 - c) * half,), (half,))
     q, s = comp.quantize_wire(send)
     for i in range(bt.s):
-        rq = lax.ppermute(q, axis, perm=list(bt.perms[i]))
-        rs = lax.ppermute(s, axis, perm=list(bt.perms[i]))
+        rq, rs = lax.ppermute((q, s), axis, perm=list(bt.perms[i]))
         if i + 1 < bt.s:
             c_next = jnp.asarray(bt.cbit[i + 1])[idx]
             buf, q, s = K.rs_step_kernel_q(buf, rq, rs, c, c_next,
@@ -134,9 +157,8 @@ def reduce_scatter_q(x, axis: Axis, algo: str = "bine", interpret=None):
     quantize points, same arithmetic); NOT bit-identical to the f32 path —
     per-element error is bounded by the received chunk's scale / 2.
 
-    The fused step kernel needs the per-rank block 256-aligned so codec
-    chunks stay blockwise; other payloads fall back to the (bit-identical)
-    shmap int8 path.
+    Rank blocks that are not whole kernel tiles run the shmap codec path
+    (:func:`shmap_fallback`).
     """
     p = shmap.axis_size(axis)
     v = x.reshape(-1).astype(jnp.float32)
@@ -145,56 +167,84 @@ def reduce_scatter_q(x, axis: Axis, algo: str = "bine", interpret=None):
     if algo not in _KIND:
         raise ValueError(f"int8 wire supports bine/recdoub, not {algo!r}")
     assert v.shape[0] % p == 0, "reduce_scatter needs len divisible by p"
-    blk = v.shape[0] // p
-    if blk % comp.WIRE_CHUNK:
+    if shmap_fallback(v.shape[0] // p, jnp.float32, "int8"):
         return shmap.reduce_scatter_q(v, axis, algo)
-    interpret = _interp(interpret)
     bt = tb.butterfly_tables(_KIND[algo], p)
-    v = v.reshape(p, blk)[jnp.asarray(bt.inv_final)].reshape(-1)
-    return _rs_core_fused_q(v, axis, bt, interpret)
+    return _rs_core_fused_q(shmap.permute_blocks(v, bt.inv_final), axis, bt,
+                            _interp(interpret))
 
 
 def allgather_q(x, axis: Axis, algo: str = "bine", interpret=None):
     """int8-wire fused allgather: this rank's block -> full vector
     (float32).  Quantize-once / move / dequantize-once, exactly as
     ``shmap.allgather_q`` — every rank decodes the same (q, scales)
-    vector, own block included, so gathered params agree across ranks."""
+    vector, own block included, so gathered params agree across ranks.
+    Blocks that are not whole kernel tiles run the shmap codec path."""
     p = shmap.axis_size(axis)
     v = x.reshape(-1).astype(jnp.float32)
     if p == 1:
         return v
     if algo not in _KIND:
         raise ValueError(f"int8 wire supports bine/recdoub, not {algo!r}")
-    interpret = _interp(interpret)
+    if shmap_fallback(v.shape[0], jnp.float32, "int8"):
+        return shmap.allgather_q(v, axis, algo)
     bt = tb.butterfly_tables(_KIND[algo], p)
-    blk = v.shape[0]
     q, s = comp.quantize_wire(v)
-    q, s = _ag_core_fused_q(q, s, axis, bt, interpret)
-    ch = comp.wire_chunk(blk)
-    fb = jnp.asarray(bt.final_block)
-    q = q.reshape(p, blk)[fb].reshape(-1)
-    s = s.reshape(p, blk // ch)[fb].reshape(-1)
-    return comp.dequantize_wire(q, s)
+    q, s = _ag_core_fused_q(q, s, axis, bt, _interp(interpret))
+    return comp.dequantize_wire(shmap.permute_blocks(q, bt.final_block),
+                                shmap.permute_blocks(s, bt.final_block))
 
 
 # ---------------------------------------------------------------------------
 # Collectives
 # ---------------------------------------------------------------------------
 
+def _rs_fused(v, axis: Axis, algo: str, interpret):
+    """Flat vector, its p blocks already in the schedule's block order ->
+    this rank's reduced block: the fused cores on whole-tile blocks, the
+    shmap cores otherwise."""
+    p = shmap.axis_size(axis)
+    fused = not shmap_fallback(v.shape[0] // p, v.dtype)
+    if algo == "ring":
+        return (_ring_rs_flat(v, axis, interpret) if fused
+                else shmap._ring_reduce_scatter(v, axis))
+    bt = tb.butterfly_tables(_KIND[algo], p)
+    return (_rs_core_fused(v, axis, bt, interpret) if fused
+            else shmap._rs_core(v, axis, bt))
+
+
+def _ag_fused(block, axis: Axis, algo: str, interpret):
+    """This rank's block -> [p * blk] gathered, still in the schedule's
+    block order (rank order for ring)."""
+    fused = not shmap_fallback(block.shape[0], block.dtype)
+    if algo == "ring":
+        return (_ring_ag_flat(block, axis, interpret) if fused
+                else shmap._ring_allgather(block, axis))
+    bt = tb.butterfly_tables(_KIND[algo], shmap.axis_size(axis))
+    return (_ag_core_fused(block, axis, bt, interpret) if fused
+            else shmap._ag_core(block, axis, bt))
+
+
+def _block_order(algo: str, p: int, inverse: bool):
+    """Static block permutation between rank order and the schedule's
+    block order (None for ring, which works in rank order)."""
+    if algo == "ring":
+        return None
+    bt = tb.butterfly_tables(_KIND[algo], p)
+    return bt.inv_final if inverse else bt.final_block
+
+
 def reduce_scatter(x, axis: Axis, algo: str = "bine", interpret=None):
     """Full vector (len % p == 0) -> this rank's reduced block."""
     p = shmap.axis_size(axis)
     if p == 1:
         return x
-    interpret = _interp(interpret)
-    if algo == "ring":
-        return _ring_reduce_scatter(x, axis, interpret)
-    bt = tb.butterfly_tables(_KIND[algo], p)
     v = x.reshape(-1)
     assert v.shape[0] % p == 0, "reduce_scatter needs len divisible by p"
-    blk = v.shape[0] // p
-    v = v.reshape(p, blk)[jnp.asarray(bt.inv_final)].reshape(-1)
-    return _rs_core_fused(v, axis, bt, interpret)
+    perm = _block_order(algo, p, inverse=True)
+    if perm is not None:
+        v = shmap.permute_blocks(v, perm)
+    return _rs_fused(v, axis, algo, _interp(interpret))
 
 
 def allgather(x, axis: Axis, algo: str = "bine", interpret=None):
@@ -202,14 +252,10 @@ def allgather(x, axis: Axis, algo: str = "bine", interpret=None):
     p = shmap.axis_size(axis)
     if p == 1:
         return x
-    interpret = _interp(interpret)
-    if algo == "ring":
-        return _ring_allgather(x, axis, interpret)
-    bt = tb.butterfly_tables(_KIND[algo], p)
     v = x.reshape(-1)
-    blk = v.shape[0]
-    v = _ag_core_fused(v, axis, bt, interpret)
-    return v.reshape(p, blk)[jnp.asarray(bt.final_block)].reshape(-1)
+    full = _ag_fused(v, axis, algo, _interp(interpret))
+    perm = _block_order(algo, p, inverse=False)
+    return full if perm is None else shmap.permute_blocks(full, perm)
 
 
 def allreduce(x, axis: Axis, algo: str = "bine", interpret=None):
@@ -219,15 +265,9 @@ def allreduce(x, axis: Axis, algo: str = "bine", interpret=None):
     if p == 1:
         return x
     interpret = _interp(interpret)
-    v = x.reshape(-1)
-    v, n = shmap._pad_to(v, p)
-    if algo == "ring":
-        block = _ring_rs_flat(v, axis, interpret)
-        full = _ring_ag_flat(block, axis, interpret)
-    else:
-        bt = tb.butterfly_tables(_KIND[algo], p)
-        v = _rs_core_fused(v, axis, bt, interpret)
-        full = _ag_core_fused(v, axis, bt, interpret)
+    v, n = shmap._pad_to(x.reshape(-1), p)
+    full = _ag_fused(_rs_fused(v, axis, algo, interpret), axis, algo,
+                     interpret)
     return full[:n].reshape(x.shape)
 
 
@@ -285,10 +325,6 @@ def _ring_rs_flat(v, axis: Axis, interpret):
     return lax.dynamic_slice(v, (idx * blk,), (blk,))
 
 
-def _ring_reduce_scatter(x, axis: Axis, interpret):
-    return _ring_rs_flat(x.reshape(-1), axis, interpret)
-
-
 def _ring_ag_flat(block, axis: Axis, interpret):
     p = shmap.axis_size(axis)
     idx = shmap.axis_index(axis)
@@ -306,10 +342,6 @@ def _ring_ag_flat(block, axis: Axis, interpret):
                                  interpret=interpret)
         send = recv
     return v
-
-
-def _ring_allgather(x, axis: Axis, interpret):
-    return _ring_ag_flat(x.reshape(-1), axis, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -336,13 +368,10 @@ def matmul_reduce_scatter(x, w, axis: Axis, algo: str = "bine",
     interpret = _interp(interpret)
     if algo == "ring":
         perm = jnp.arange(p, dtype=jnp.int32)  # ring scatters in rank order
-        y = K.matmul_pack_kernel(x, w, perm, interpret=interpret)
-        out = _ring_rs_flat(y.reshape(-1), axis, interpret)
     else:
-        bt = tb.butterfly_tables(_KIND[algo], p)
-        y = K.matmul_pack_kernel(x, w, jnp.asarray(bt.inv_final),
-                                 interpret=interpret)
-        out = _rs_core_fused(y.reshape(-1), axis, bt, interpret)
+        perm = _block_order(algo, p, inverse=True)
+    y = K.matmul_pack_kernel(x, w, perm, interpret=interpret)
+    out = _rs_fused(y.reshape(-1), axis, algo, interpret)
     return out.reshape(m // p, n)
 
 
@@ -362,12 +391,9 @@ def allgather_matmul(x, w, axis: Axis, algo: str = "bine", interpret=None):
         return y.astype(jnp.result_type(x, w))
     interpret = _interp(interpret)
     mb, k = x.shape
-    if algo == "ring":
-        g = _ring_ag_flat(x.reshape(-1), axis, interpret)
+    g = _ag_fused(x.reshape(-1), axis, algo, interpret)
+    perm = _block_order(algo, p, inverse=False)
+    if perm is None:
         perm = jnp.arange(p, dtype=jnp.int32)
-    else:
-        bt = tb.butterfly_tables(_KIND[algo], p)
-        g = _ag_core_fused(x.reshape(-1), axis, bt, interpret)
-        perm = jnp.asarray(bt.final_block)
     return K.gather_matmul_kernel(g.reshape(p * mb, k), w, perm,
                                   interpret=interpret)

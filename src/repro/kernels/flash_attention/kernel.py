@@ -90,7 +90,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_kernel(q, k, v, *, window=None, causal: bool = True,
                            bq: int = 128, bk: int = 128, scale=None,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: [B, nkv, g, Tq, hd]; k, v: [B, nkv, Tk, hd] -> like q.
 
     Tq % bq == 0 and Tk % bk == 0 (ops.py pads and unpads).

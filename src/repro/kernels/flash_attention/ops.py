@@ -27,7 +27,7 @@ def _pad_to(x, axis: int, mult: int):
 @partial(jax.jit, static_argnames=("window", "causal", "bq", "bk",
                                    "interpret"))
 def flash_attention(q, k, v, *, window=None, causal: bool = True,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+                    bq: int = 128, bk: int = 128, interpret: bool = False):
     """q: [B, Tq, nh, hd]; k, v: [B, Tk, nkv, hd] -> [B, Tq, nh, hd]."""
     B, Tq, nh, hd = q.shape
     Tk, nkv = k.shape[1], k.shape[2]

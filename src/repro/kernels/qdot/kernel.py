@@ -25,7 +25,7 @@ def _qacc_kernel(q_ref, s_ref, a_ref, o_ref, *, chunk: int):
 
 
 def qacc_kernel(q, scales, acc, *, block_chunks: int = 64,
-                interpret: bool = True):
+                interpret: bool = False):
     """q: [C, chunk] int8; scales: [C, 1] f32; acc: [C, chunk] f32."""
     C, chunk = q.shape
     bn = min(block_chunks, C)

@@ -10,7 +10,7 @@ from .kernel import qacc_kernel
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def dequant_accumulate(q, scales, acc, interpret: bool = True):
+def dequant_accumulate(q, scales, acc, interpret: bool = False):
     """q: [C, chunk] int8; scales: [C, 1] f32; acc: [C, chunk] f32."""
     C = q.shape[0]
     bn = 64

@@ -24,7 +24,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_kernel(x, w, *, eps: float = 1e-6, block_rows: int = 256,
-                   interpret: bool = True):
+                   interpret: bool = False):
     """x: [N, d]; w: [d] -> [N, d]."""
     N, d = x.shape
     bn = min(block_rows, N)

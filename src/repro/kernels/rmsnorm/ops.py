@@ -11,7 +11,7 @@ from .kernel import rmsnorm_kernel
 
 
 @partial(jax.jit, static_argnames=("eps", "interpret"))
-def rmsnorm(x, w, eps: float = 1e-6, interpret: bool = True):
+def rmsnorm(x, w, eps: float = 1e-6, interpret: bool = False):
     shape = x.shape
     d = shape[-1]
     xf = x.reshape(-1, d)

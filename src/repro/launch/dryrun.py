@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import base as cfgbase
 from repro.launch import hlo as H
 from repro.launch.mesh import dp_axes as mesh_dp_axes, make_production_mesh
-from repro.compat import set_mesh
+from jax import set_mesh
 
 
 def sds(shape, dtype, sharding=None):
@@ -45,7 +45,7 @@ def input_specs(arch: str, shape: str, mesh, backend: str = "bine",
     callable to lower.  Returns dict(step=fn, args=tuple_of_SDS, meta=...)."""
     from repro.models import transformer as T
     from repro.serve.engine import ServeConfig, cache_specs, make_serve_fns
-    from repro.train.step import TrainConfig, make_train_step
+    from repro.train.step import TrainConfig, for_mesh, make_train_step
     from repro.models.sharding import param_specs
 
     from repro.models import sharding as _sh
@@ -69,9 +69,9 @@ def input_specs(arch: str, shape: str, mesh, backend: str = "bine",
         lambda l, s: sds(l.shape, l.dtype, ns(s)), params_shapes, pspecs)
 
     if sc.kind == "train":
-        tcfg = TrainConfig(backend=backend, dp_axes=dp,
-                           bucket_bytes=bucket_bytes, tuning=tuning,
-                           wire_dtype=wire_dtype)
+        tcfg = for_mesh(TrainConfig(backend=backend, dp_axes=dp,
+                                    bucket_bytes=bucket_bytes, tuning=tuning,
+                                    wire_dtype=wire_dtype), mesh)
         step_fn, shardings, layout = make_train_step(cfg, tcfg, mesh,
                                                      params_shapes)
         state_shapes = jax.eval_shape(

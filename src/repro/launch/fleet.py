@@ -86,23 +86,21 @@ def run_serve(args) -> None:
     import jax
     import numpy as np
 
-    from repro.compat import set_mesh
+    from jax import set_mesh
+
     from repro.configs import base as cfgbase
     from repro.fleet import Fleet, FleetConfig
+    from repro.launch.mesh import enable_compile_cache, mesh_from_arg
     from repro.models import transformer as T
     from repro.serve.engine import ServeConfig, make_serve_fns, page_len
     from repro.serve.scheduler import poisson_trace
 
+    enable_compile_cache()
     cfg = cfgbase.get_config(args.arch)
     if args.reduced:
         cfg = cfgbase.reduced(cfg)
 
-    n_dev = len(jax.devices())
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-    else:
-        shape = (n_dev, 1)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = mesh_from_arg(args.mesh, ("data", "model"))
     tp = int(mesh.shape["model"])
 
     # the placement report for this fleet shape on the requested topology

@@ -25,8 +25,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
+from jax import set_mesh
+
 from repro.configs import base as cfgbase
+from repro.launch.mesh import (dp_axes as mesh_dp_axes, enable_compile_cache,
+                               mesh_from_arg)
 from repro.models import transformer as T
 from repro.serve.engine import ServeConfig, make_serve_fns, page_len
 from repro.serve.scheduler import ContinuousBatchingScheduler, poisson_trace
@@ -87,18 +90,13 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = cfgbase.get_config(args.arch)
     if args.reduced:
         cfg = cfgbase.reduced(cfg)
 
-    n_dev = len(jax.devices())
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-        axes = ("pod", "data", "model")[-len(shape):]
-    else:
-        shape, axes = (n_dev, 1), ("data", "model")
-    mesh = jax.make_mesh(shape, axes)
-    dp_axes = tuple(a for a in axes if a in ("pod", "data"))
+    mesh = mesh_from_arg(args.mesh)
+    dp_axes = mesh_dp_axes(mesh)
 
     S = page_len(cfg, args.prompt_len_max, args.max_new)
     scfg = ServeConfig(dp_axes=dp_axes, backend=args.backend)
@@ -141,6 +139,12 @@ def main(argv=None):
     done = [r for r in trace if r.finished]
     print(f"[serve] finished {len(done)}/{len(trace)}; sample request 0 ids:",
           trace[0].generated[:16])
+    if len(done) != len(trace):
+        raise SystemExit(f"[serve] {len(trace) - len(done)} requests did "
+                         f"not finish")
+    return {"arch": cfg.name, "n_layers": cfg.n_layers, "wall_s": dt,
+            "page_len": S, "requests": trace,
+            "trace_counts": dict(fns.trace_counts), **stats}
 
 
 if __name__ == "__main__":

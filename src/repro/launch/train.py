@@ -7,26 +7,30 @@ straggler monitor, and restart-on-failure.
 
   python -m repro.launch.train --arch phi4-mini-3.8b --reduced \\
       --mesh 1,2,4 --steps 200 --batch 8 --seq 128 --backend bine
+
+``--layers N`` cuts depth only (every width stays as published), so a
+full-width model fits one chip's memory: phi4-mini-3.8b at one layer is
+about 715 M parameters, 11.4 GB at 16 bytes a parameter.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
-import os
+import math
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import base as cfgbase
+from repro.launch.mesh import (dp_axes as mesh_dp_axes, enable_compile_cache,
+                               mesh_from_arg)
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig, Prefetcher, make_batch
 from repro.train.runtime import StragglerMonitor
 from repro.train.step import TrainConfig, make_init_fns, make_train_step
-from repro.compat import set_mesh
 
 
 def main(argv=None):
@@ -34,6 +38,9 @@ def main(argv=None):
     ap.add_argument("--arch", default="phi4-mini-3.8b")
     ap.add_argument("--reduced", action="store_true",
                     help="tiny same-family config (CPU-runnable)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the first N layers (depth cut; widths as "
+                         "published); 0 keeps all")
     ap.add_argument("--mesh", default="",
                     help="pod,data,model (default: all devices on data)")
     ap.add_argument("--steps", type=int, default=100)
@@ -59,18 +66,18 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = cfgbase.get_config(args.arch)
     if args.reduced:
         cfg = cfgbase.reduced(cfg)
+    if args.layers:
+        if not 0 < args.layers <= cfg.n_layers:
+            raise SystemExit(f"--layers must be in 1..{cfg.n_layers}, "
+                             f"got {args.layers}")
+        cfg = cfg.replace(n_layers=args.layers)
 
-    n_dev = len(jax.devices())
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split(","))
-        axes = ("pod", "data", "model")[-len(shape):]
-    else:
-        shape, axes = (n_dev, 1), ("data", "model")
-    mesh = jax.make_mesh(shape, axes)
-    dp_axes = tuple(a for a in axes if a in ("pod", "data"))
+    mesh = mesh_from_arg(args.mesh)
+    dp_axes = mesh_dp_axes(mesh)
 
     acfg = AdamWConfig(lr=args.lr, warmup_steps=args.warmup,
                        total_steps=args.steps)
@@ -95,7 +102,7 @@ def main(argv=None):
     cpr = ckpt.AsyncCheckpointer(args.ckpt_dir) if args.ckpt_dir else None
     monitor = StragglerMonitor()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_p(key)
         state = init_s(params)
         start = 0
@@ -109,6 +116,7 @@ def main(argv=None):
                 print(f"[train] resumed from step {start}")
 
         pf = Prefetcher(dcfg, start_step=start)
+        losses, gnorms, step_s = [], [], []
         try:
             t_all = time.time()
             for s in range(start, args.steps):
@@ -119,12 +127,17 @@ def main(argv=None):
                 params, state, metrics = step_fn(params, state, batch)
                 loss = float(metrics["loss"])
                 dt = time.time() - t0
+                if not math.isfinite(loss):
+                    raise FloatingPointError(f"step {s}: loss {loss}")
+                losses.append(loss)
+                gnorms.append(float(metrics["grad_norm"]))
+                step_s.append(dt)
                 if monitor.observe(s, dt):
                     print(f"[straggler] step {s} took {dt:.3f}s "
                           f"(ewma {monitor.ewma:.3f}s)")
                 if s % args.log_every == 0 or s == args.steps - 1:
                     print(f"step {s:5d} loss {loss:.4f} "
-                          f"gnorm {float(metrics['grad_norm']):.3f} "
+                          f"gnorm {gnorms[-1]:.3f} "
                           f"lr {float(metrics['lr']):.2e} {dt*1e3:.0f}ms")
                 if cpr and (s + 1) % args.ckpt_every == 0:
                     cpr.save(s + 1, {"params": params, "state": state})
@@ -137,6 +150,9 @@ def main(argv=None):
                   f"stragglers flagged: {len(monitor.flagged)}")
         finally:
             pf.close()
+    return {"arch": cfg.name, "n_layers": cfg.n_layers, "n_params": n_params,
+            "losses": losses, "grad_norms": gnorms, "step_s": step_s,
+            "tokens_per_step": args.batch * args.seq, "params": params}
 
 
 if __name__ == "__main__":

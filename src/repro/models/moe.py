@@ -31,7 +31,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 from .layers import dense, init_dense
 
@@ -47,13 +46,11 @@ def a2a_backend(n: int, buffer_bytes: int, topology: str = None) -> str:
 
     ``buffer_bytes`` is the full per-rank alltoall buffer (all n
     destination blocks — the decision table's full-vector convention).
-    Consults the topology decision table (repro.topology).  Returns "xla"
-    (linear lax.all_to_all) when the nested-manual limitation applies:
-    new-jax Shardy rejects lax.axis_index inside a nested manual region,
-    which the log butterflies need for their step tables.
+    Consults the topology decision table (repro.topology).  The log
+    butterflies read this chip's model-axis index from
+    ``shmap.axis_index_hints`` (``_moe_ep`` passes it as data: Shardy
+    rejects ``lax.axis_index`` inside a nested manual region).
     """
-    if not compat.NESTED_AXIS_INDEX_OK:
-        return "xla"
     from repro.topology import select_backend
     return select_backend("alltoall", n, buffer_bytes,
                           topology or A2A_TOPOLOGY)
@@ -86,18 +83,6 @@ def _route(router_w, cfg, xt):
     return gate_vals, gate_idx, aux
 
 
-def _model_axis_is_manual() -> bool:
-    """True when tracing inside a region that is already manual over the
-    model axis (0.4.x full-manual train step): the EP path's nested
-    shard_map over that axis cannot apply there — fall back to dense."""
-    from .sharding import MODEL_AXIS
-    try:
-        compat.axis_size(MODEL_AXIS)
-        return True
-    except Exception:
-        return False
-
-
 def moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     """x: [B, T, d] -> (out [B, T, d], aux_loss scalar)."""
     from . import sharding as sh
@@ -105,7 +90,7 @@ def moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     n = sh.model_parallel()
     B, T, d = x.shape
     EB = cfg.n_experts * cfg.ep_blocks
-    if n > 1 and EB % n == 0 and T % n == 0 and not _model_axis_is_manual():
+    if n > 1 and EB % n == 0 and T % n == 0:
         return _moe_ep(p, cfg, x, n)
     return _moe_dense(p, cfg, x)
 
@@ -181,6 +166,10 @@ def _moe_ep(p, cfg, x, n: int) -> Tuple[jax.Array, jax.Array]:
         # idx_arr: [1] = this chip's model-axis index (passed as a sharded
         # arange: lax.axis_index inside a NESTED shard_map trips a Shardy
         # lowering bug — "axis already bound by parent manual computation")
+        with coll.axis_index_hints({MODEL_AXIS: idx_arr[0]}):
+            return _body(xl, router, wi, wg, wo, idx_arr)
+
+    def _body(xl, router, wi, wg, wo, idx_arr):
         Nl = B * (T // n)
         xt = xl.reshape(Nl, d)
         gate_vals, gate_idx, aux = _route(router, cfg, xt)
@@ -213,8 +202,7 @@ def _moe_ep(p, cfg, x, n: int) -> Tuple[jax.Array, jax.Array]:
         # ---- dispatch alltoall (selector-chosen algorithm) ----
         # a2a comes from the topology decision table: the log butterflies
         # for payloads/rank-counts where they are predicted faster, XLA's
-        # linear alltoall otherwise.  On new-jax Shardy, a2a_backend pins
-        # "xla" (lax.axis_index is rejected in nested manual regions).
+        # linear alltoall otherwise.
         if a2a == "xla":
             recv = lax.all_to_all(send, MODEL_AXIS, 0, 0, tiled=False)
             recv_blk = lax.all_to_all(send_blk, MODEL_AXIS, 0, 0, tiled=False)
@@ -255,7 +243,7 @@ def _moe_ep(p, cfg, x, n: int) -> Tuple[jax.Array, jax.Array]:
         out = jnp.zeros((Nl, d), part.dtype).at[stok].add(part)
         return out.reshape(B, T // n, d), aux
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         in_specs=(P(None, MODEL_AXIS, None), P(), P(MODEL_AXIS, None, None),
                   P(MODEL_AXIS, None, None), P(MODEL_AXIS, None, None),

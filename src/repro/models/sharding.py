@@ -28,13 +28,6 @@ MODEL_AXIS = "model"
 
 _ENABLED = True  # flipped off in pure-CPU single-device unit tests
 
-#: layout hints only (never numerics): with_sharding_constraint emission.
-#: Suppressed on jax 0.4.x inside the partial-auto train body, where
-#: auto-axis constraints under multiple manual axes trip an XLA SPMD
-#: partitioner RET_CHECK ("Incompatible manual sharding"); GSPMD then
-#: derives model-axis layouts from the parameter shardings alone.
-_HINTS = True
-
 #: distribution context, set by the step builders (train/serve/dryrun).
 #: n_model == 1 means no tensor/sequence parallelism (unit tests).
 _CTX = {"n_model": 1}
@@ -68,25 +61,10 @@ def strategy(cfg) -> str:
     return "pure_sp"
 
 
-from contextlib import contextmanager
-
-
-@contextmanager
-def constraint_hints_disabled():
-    """Suppress shard()/constrain_params hints (layout only) while tracing."""
-    global _HINTS
-    prev = _HINTS
-    _HINTS = False
-    try:
-        yield
-    finally:
-        _HINTS = prev
-
-
 def shard(x, *spec):
     """Constrain activation sharding (model axis only).  Outside a mesh
     context (single-device unit tests) this is a no-op."""
-    if not _ENABLED or not _HINTS:
+    if not _ENABLED:
         return x
     try:
         return jax.lax.with_sharding_constraint(x, P(*spec))
@@ -219,7 +197,7 @@ def param_specs(cfg, params: Any) -> Any:
 
 def constrain_params(cfg, params):
     """Apply the model-axis sharding constraints to a param pytree."""
-    if not _ENABLED or not _HINTS:
+    if not _ENABLED:
         return params
     specs = param_specs(cfg, params)
 

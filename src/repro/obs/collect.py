@@ -143,13 +143,9 @@ def record(collective: str, backend: str, p: int, payload_bytes: int,
 
 
 def _wire_scale(wire_dtype: str) -> float:
-    if wire_dtype == "float32":
-        return 1.0
-    try:
-        from repro.collectives.compression import wire_factor
-        return wire_factor(wire_dtype)
-    except Exception:
-        return 1.0
+    """Wire bytes per f32 payload byte; an unknown wire dtype raises."""
+    from repro.collectives.compression import wire_factor
+    return wire_factor(wire_dtype)
 
 
 def record_api(cfg, collective: str, p: int, nbytes: int,

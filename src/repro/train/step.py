@@ -48,7 +48,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.collectives import shmap
 from repro.models import transformer as T
 from repro.models.sharding import constrain_params, param_specs
@@ -88,6 +87,9 @@ class TrainConfig:
     #: per-topology choice cached in the decision table, 0 disables
     #: bucketing (per-leaf collectives), >0 is an explicit capacity
     bucket_bytes: int = -1
+    #: whether the step may run Mosaic kernels; :func:`for_mesh` clears it
+    #: on meshes where the step's shard_map stays partially manual
+    fused_kernels: bool = True
 
     def __post_init__(self):
         # Fail at construction, not silently mid-step: the old _wire_cast
@@ -137,8 +139,17 @@ def _backend_for_bytes(tcfg: TrainConfig, collective: str, p: int,
     if tcfg.backend != "auto":
         return tcfg.backend
     from repro.topology import select_backend
-    return select_backend(collective, p, nbytes, tcfg.topology,
-                          tuning=tcfg.tuning)
+    return _runnable(tcfg, select_backend(collective, p, nbytes,
+                                          tcfg.topology, tuning=tcfg.tuning))
+
+
+def _runnable(tcfg: TrainConfig, backend: str) -> str:
+    """An auto-resolved backend the step can run: without Mosaic kernels
+    (``fused_kernels=False``) pallas_fused runs as bine — the same
+    schedule, as shmap ops."""
+    if backend == "pallas_fused" and not tcfg.fused_kernels:
+        return "bine"
+    return backend
 
 
 def _backend_for(tcfg: TrainConfig, collective: str, arr,
@@ -388,6 +399,7 @@ def _bucket_decision(tcfg: TrainConfig, collective: str, p: int,
         from repro.topology import select_wire
         b, w = select_wire(collective, p, f32_bytes, tcfg.topology,
                            tuning=tcfg.tuning)
+        b = _runnable(tcfg, b)
         if tcfg.backend != "auto":
             b = tcfg.backend
             if b not in _CODEC_BACKENDS:
@@ -549,11 +561,30 @@ def init_train_state_spmd(model_cfg, tcfg: TrainConfig, params, n_dp: int):
 # The step
 # ---------------------------------------------------------------------------
 
+def for_mesh(tcfg: TrainConfig, mesh) -> TrainConfig:
+    """``tcfg`` as the step runs it on ``mesh``.
+
+    With a non-DP axis above 1 the step's shard_map leaves that axis to
+    GSPMD, and Mosaic kernels cannot be partitioned there: an explicit
+    ``pallas_fused`` is refused, and ``auto`` runs bine wherever its
+    table picks pallas_fused."""
+    if _fully_manual(tcfg, mesh):
+        return tcfg
+    if tcfg.backend == "pallas_fused":
+        raise ValueError(
+            "backend='pallas_fused' needs every non-DP mesh axis to have "
+            f"size 1 (mesh {dict(mesh.shape)}): its Mosaic kernels cannot "
+            "run in a shard_map that leaves the model axis to GSPMD.  Use "
+            "backend='bine' (the same schedule) or 'auto'.")
+    return tcfg.replace(fused_kernels=False)
+
+
 def make_train_step(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
     """Returns (jitted step, in/out shardings dict).
 
     step(params, state, batch) -> (params, state, metrics)
     """
+    tcfg = for_mesh(tcfg, mesh)
     n_dp = int(np.prod([mesh.shape[a] for a in tcfg.dp_axes]))
     if n_dp > 1:
         from repro.collectives.api import executable_at
@@ -594,20 +625,9 @@ def make_train_step(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
 
     def body(params, state, batch, ranks):
         # ranks[a] is this shard's index along manual axis a, passed as data
-        # (a sharded arange): lax.axis_index of a manual axis does not lower
-        # under partial-auto shard_map on jax 0.4.x (PartitionId) nor inside
-        # nested manual regions on new jax (Shardy) — see shmap.axis_index_hints.
+        # (a sharded arange) — see shmap.axis_index_hints.
         with shmap.axis_index_hints({a: r[0] for a, r in ranks.items()}):
-            if compat.HAS_NATIVE_SHARD_MAP:
-                return _body_inner(params, state, batch)
-            # 0.4.x: partial-auto cannot lower our collectives (ppermute
-            # of a manual axis crashes the SPMD partitioner), so the body
-            # runs fully manual (see _manual_axes) and model-axis GSPMD
-            # parallelism degrades to replication.  Sharding hints would
-            # reference a now-manual axis — drop them (layout only,
-            # numerics-free).
-            with _sh.constraint_hints_disabled():
-                return _body_inner(params, state, batch)
+            return _body_inner(params, state, batch)
 
     def _body_inner(params, state, batch):
         params = constrain_params(model_cfg, params)
@@ -767,7 +787,7 @@ def make_train_step(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
     metrics_out = P()
 
     rank_in = {a: P(a) for a in tcfg.dp_axes}
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_in, state_in, batch_in, rank_in),
         out_specs=(param_in, state_in,
@@ -811,18 +831,20 @@ def _rank_arrays(tcfg: TrainConfig, mesh):
             for a in tcfg.dp_axes}
 
 
-def _manual_axes(tcfg: TrainConfig, mesh):
-    """Manual axes of the step's shard_map.
+def _fully_manual(tcfg: TrainConfig, mesh) -> bool:
+    """True when every non-DP mesh axis has size 1: nothing is left for
+    GSPMD to partition, so the step's shard_map goes manual over all axes
+    (Mosaic kernels refuse to lower in a partially manual region)."""
+    return all(n == 1 for a, n in mesh.shape.items()
+               if a not in tcfg.dp_axes)
 
-    Modern jax: the DP axes only (partial-auto; "model" stays under
-    GSPMD).  jax 0.4.x: ALL axes — its SPMD partitioner cannot lower
-    collective-permute inside a partial-auto region, so the model axis
-    goes manual too and tensor parallelism degrades to replication
-    (numerics unchanged; the Bine DP collectives are the point here).
-    """
-    if compat.HAS_NATIVE_SHARD_MAP:
-        return set(tcfg.dp_axes)
-    return set(mesh.axis_names)
+
+def _manual_axes(tcfg: TrainConfig, mesh):
+    """Manual axes of the step's shard_map: the DP axes, with the model
+    axis left to GSPMD, or every axis when :func:`_fully_manual`."""
+    if _fully_manual(tcfg, mesh):
+        return set(mesh.axis_names)
+    return set(tcfg.dp_axes)
 
 
 def _merge_spec(model_spec, zd: int, dp_axes, ndim: int):
@@ -835,6 +857,7 @@ def _merge_spec(model_spec, zd: int, dp_axes, ndim: int):
 def make_init_fns(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
     """jitted init of params (replicated over DP, model-sharded) and of the
     sharded train state (opt shards built in-place, no full fp32 copy)."""
+    tcfg = for_mesh(tcfg, mesh)
     n_dp = int(np.prod([mesh.shape[a] for a in tcfg.dp_axes]))
     from repro.models import sharding as _sh
     _sh.set_model_parallel(mesh.shape.get(tcfg.model_axis, 1))
@@ -864,7 +887,7 @@ def make_init_fns(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
 
     init_params_fn = jax.jit(init_p)
     rank_in = {a: P(a) for a in tcfg.dp_axes}
-    smapped_init = compat.shard_map(
+    smapped_init = jax.shard_map(
         init_s, mesh=mesh, in_specs=(param_in, rank_in),
         out_specs=state_out,
         axis_names=_manual_axes(tcfg, mesh), check_vma=False)

@@ -160,7 +160,7 @@ def _build_fn(collective: str, backend: str, p: int, mesh, axis: str,
     from jax.sharding import PartitionSpec as P
 
     from repro.collectives import api
-    from repro.compat import shard_map
+    from jax import shard_map
 
     cfg = api.CollectiveConfig(backend=backend, wire_dtype=wire_dtype)
     if topology is not None:
@@ -178,8 +178,10 @@ def _build_fn(collective: str, backend: str, p: int, mesh, axis: str,
     else:
         raise ValueError(f"probe cannot drive collective {collective!r}")
 
+    # check_vma=False, as in the train step's shard_map: the probe times
+    # the program production runs
     return jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                             out_specs=P(axis)))
+                             out_specs=P(axis), check_vma=False))
 
 
 def time_collective(collective: str, backend: str, p: int, nbytes: int,
@@ -226,14 +228,15 @@ def time_collective(collective: str, backend: str, p: int, nbytes: int,
 
 def _mesh_for(p: int, axis: str):
     import jax
-    from jax.sharding import Mesh
+
+    from repro.launch.mesh import make_mesh
 
     devs = jax.devices()
     if len(devs) < p:
         raise RuntimeError(
             f"probe needs {p} devices, have {len(devs)} "
             f"(set --xla_force_host_platform_device_count or --devices)")
-    return Mesh(np.array(devs[:p]), (axis,))
+    return make_mesh((p,), (axis,), devices=devs[:p])
 
 
 def probe_backends(collective: str,

@@ -17,7 +17,7 @@ File format::
     {
       "format": 1,
       "device_kind": "cpu", "topology": "tpu_multipod", "p": 4,
-      "provenance": {"grid": "tiny", "timestamp": null, "jax": "0.4.37",
+      "provenance": {"grid": "tiny", "timestamp": null, "jax": "0.9.0",
                      "platform": "cpu"},
       "measurements": [
         {"collective": "allreduce", "backend": "bine", "p": 4,

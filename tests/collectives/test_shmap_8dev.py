@@ -5,9 +5,10 @@ import pytest
 CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((8,), ("x",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 from repro.collectives import api, shmap
-from repro.compat import shard_map
+from jax import shard_map
 
 rng = np.random.RandomState(0)
 TOL = dict(rtol=1e-4, atol=1e-5)
@@ -109,7 +110,8 @@ for dim in (0, 1):
             np.testing.assert_allclose(out2[r], full, **TOL)
 
 # hierarchical + grad flow
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh2 = make_mesh((2, 4), ("pod", "data"))
 xh = rng.randn(8, 512).astype(np.float32)
 f = jax.jit(shard_map(
     lambda v: shmap.allreduce_hierarchical(v, "data", "pod", "bine"),

@@ -60,33 +60,37 @@ _FUSED_DISPATCH = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.collectives import api
-from repro.compat import shard_map
+from jax import shard_map
 
 rng = np.random.RandomState(0)
 
 for p in (4, 8):
     mesh = Mesh(np.asarray(jax.devices()[:p]), ("x",))
+    # interpreted kernels need check_vma=False (ops.default_interpret)
     def under(fn):
         return jax.jit(shard_map(fn, mesh=mesh, in_specs=P("x"),
-                                 out_specs=P("x")))
+                                 out_specs=P("x"), check_vma=False))
 
     x = rng.randn(p, 512).astype(np.float32)
     blocks = rng.randn(p, 64).astype(np.float32)
+    # rank blocks of whole kernel tiles (1024 f32), so the kernels run
+    xk = rng.randn(p, p * 1024).astype(np.float32)
+    bk = rng.randn(p, 1024).astype(np.float32)
     for algo in ("bine", "recdoub", "ring"):
         cfg = api.CollectiveConfig(backend="pallas_fused", fused_algo=algo,
                                    small_cutoff_bytes=0)
-        out = np.asarray(under(lambda v: api.allreduce(v, "x", cfg))(x))
-        np.testing.assert_allclose(out, np.tile(x.sum(0), (p, 1)),
+        out = np.asarray(under(lambda v: api.allreduce(v, "x", cfg))(xk))
+        np.testing.assert_allclose(out, np.tile(xk.sum(0), (p, 1)),
                                    rtol=1e-4, atol=1e-5)
         out = np.asarray(under(
-            lambda v: api.reduce_scatter(v.reshape(-1), "x", cfg))(x))
+            lambda v: api.reduce_scatter(v.reshape(-1), "x", cfg))(xk))
         np.testing.assert_allclose(out.reshape(p, -1),
-                                   x.sum(0).reshape(p, -1),
+                                   xk.sum(0).reshape(p, -1),
                                    rtol=1e-4, atol=1e-5)
         out = np.asarray(under(
-            lambda v: api.allgather(v.reshape(-1), "x", cfg))(blocks))
+            lambda v: api.allgather(v.reshape(-1), "x", cfg))(bk))
         np.testing.assert_allclose(
-            out.reshape(p, -1), np.tile(blocks.reshape(-1), (p, 1)),
+            out.reshape(p, -1), np.tile(bk.reshape(-1), (p, 1)),
             rtol=1e-4, atol=1e-5)
 
     # fallback family: rooted + alltoall through the pallas_fused dispatch

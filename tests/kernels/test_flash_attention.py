@@ -35,7 +35,8 @@ def test_flash_vs_ref(B, T, nh, nkv, hd, window, dtype, tol):
     q = jnp.asarray(rng.randn(B, T, nh, hd), dtype)
     k = jnp.asarray(rng.randn(B, T, nkv, hd), dtype)
     v = jnp.asarray(rng.randn(B, T, nkv, hd), dtype)
-    out = flash_attention(q, k, v, window=window, bq=128, bk=128)
+    out = flash_attention(q, k, v, window=window, bq=128, bk=128,
+                          interpret=True)
     ref = _ref(q, k, v, window)
     err = np.max(np.abs(np.asarray(out, np.float32)
                         - np.asarray(ref, np.float32)))
@@ -49,6 +50,6 @@ def test_block_size_sweep():
     v = jnp.asarray(rng.randn(1, 256, 2, 64), jnp.float32)
     ref = _ref(q, k, v, None)
     for bq, bk in [(32, 32), (64, 128), (128, 64), (256, 256)]:
-        out = flash_attention(q, k, v, bq=bq, bk=bk)
+        out = flash_attention(q, k, v, bq=bq, bk=bk, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)

@@ -24,23 +24,25 @@ def test_rs_step_kernel_matches_ref(h):
     buf = jnp.asarray(rng.randn(2 * h).astype(np.float32))
     recv = jnp.asarray(rng.randn(h).astype(np.float32))
     for c in (0, 1):
-        out = K.rs_step_kernel(buf, recv, c)
+        out = K.rs_step_kernel(buf, recv, c, interpret=True)
         np.testing.assert_array_equal(np.asarray(out),
                                       np.asarray(R.rs_step_ref(buf, recv, c)))
         for cn in (0, 1):
-            o, s = K.rs_step_kernel(buf, recv, c, cn)
+            o, s = K.rs_step_kernel(buf, recv, c, cn, interpret=True)
             ro, rs = R.rs_step_ref(buf, recv, c, cn)
             np.testing.assert_array_equal(np.asarray(o), np.asarray(ro))
             np.testing.assert_array_equal(np.asarray(s), np.asarray(rs))
 
 
-@pytest.mark.parametrize("h", [8, 512, 6])
-def test_ag_step_kernel_matches_ref(h):
-    buf = jnp.asarray(rng.randn(h).astype(np.float32))
-    recv = jnp.asarray(rng.randn(h).astype(np.float32))
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16, np.int8],
+                         ids=["f32", "bf16", "int8"])
+@pytest.mark.parametrize("h", [128, 512, 1 << 17])
+def test_ag_step_kernel_matches_ref(h, dtype):
+    buf = jnp.asarray(rng.randint(-100, 100, h), dtype)
+    recv = jnp.asarray(rng.randint(-100, 100, h), dtype)
     for c in (0, 1):
         np.testing.assert_array_equal(
-            np.asarray(K.ag_step_kernel(buf, recv, c)),
+            np.asarray(K.ag_step_kernel(buf, recv, c, interpret=True)),
             np.asarray(R.ag_step_ref(buf, recv, c)))
 
 
@@ -48,14 +50,16 @@ def test_ring_update_kernel_matches_ref():
     v = jnp.asarray(rng.randn(96).astype(np.float32))
     recv = jnp.asarray(rng.randn(24).astype(np.float32))
     for ridx in range(4):
-        got = K.ring_update_kernel(v, recv, ridx, accumulate=False)
+        got = K.ring_update_kernel(v, recv, ridx, accumulate=False,
+                                   interpret=True)
         np.testing.assert_array_equal(
             np.asarray(got),
             np.asarray(R.ring_update_ref(v, recv, ridx, accumulate=False)))
-        got = K.ring_update_kernel(v, recv, ridx)
+        got = K.ring_update_kernel(v, recv, ridx, interpret=True)
         exp = R.ring_update_ref(v, recv, ridx)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
-        got_v, upd = K.ring_update_kernel(v, recv, ridx, return_updated=True)
+        got_v, upd = K.ring_update_kernel(v, recv, ridx, return_updated=True,
+                                          interpret=True)
         np.testing.assert_array_equal(np.asarray(got_v), np.asarray(exp))
         # the second output is the updated block == the next ring send
         np.testing.assert_array_equal(
@@ -69,10 +73,12 @@ def test_matmul_kernels_match_refs(m, k, n, p):
     w = jnp.asarray(rng.randn(k, n).astype(np.float32))
     perm = np.asarray(rng.permutation(p), np.int32)
     np.testing.assert_allclose(
-        np.asarray(K.matmul_pack_kernel(x, w, jnp.asarray(perm))),
+        np.asarray(K.matmul_pack_kernel(x, w, jnp.asarray(perm),
+                                        interpret=True)),
         np.asarray(R.matmul_pack_ref(x, w, perm)), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(
-        np.asarray(K.gather_matmul_kernel(x, w, jnp.asarray(perm))),
+        np.asarray(K.gather_matmul_kernel(x, w, jnp.asarray(perm),
+                                          interpret=True)),
         np.asarray(R.gather_matmul_ref(x, w, perm)), rtol=1e-5, atol=1e-5)
 
 
@@ -107,32 +113,46 @@ def test_plan_rejects_unknown():
 CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((8,), ("x",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("x",))
 from repro.collectives import api, shmap
-from repro.compat import shard_map
+from jax import shard_map
 from repro.kernels import collectives as fused
 
 rng = np.random.RandomState(0)
 
+# interpreted kernels need check_vma=False (ops.default_interpret)
 def under(fn, in_spec=P("x"), out_spec=P("x"), m=mesh):
-    return jax.jit(shard_map(fn, mesh=m, in_specs=in_spec, out_specs=out_spec))
+    return jax.jit(shard_map(fn, mesh=m, in_specs=in_spec, out_specs=out_spec,
+                             check_vma=False))
 
-x = rng.randn(8, 2048).astype(np.float32)
-blocks = rng.randn(8, 256).astype(np.float32)
+def kernels_in(fn, arg):
+    return "pallas_call" in str(jax.make_jaxpr(under(fn))(arg))
+
+# rank blocks of whole tiles (1024 f32) run the kernels; unaligned ones
+# (256) run the shmap schedule -- both bit-identical to the shmap backend
+x = rng.randn(8, 8192).astype(np.float32)
+blocks = rng.randn(8, 1024).astype(np.float32)
+x_un = rng.randn(8, 2048).astype(np.float32)
+blocks_un = rng.randn(8, 256).astype(np.float32)
 for algo in fused.ALGOS:
     cfg = api.CollectiveConfig(backend="pallas_fused", fused_algo=algo,
                                small_cutoff_bytes=0)
     ref = api.CollectiveConfig(backend=algo, small_cutoff_bytes=0)
-    for name, fn, arg in (
-        ("allreduce", lambda v, c: api.allreduce(v, "x", c), x),
+    for name, fn, arg, arg_un in (
+        ("allreduce", lambda v, c: api.allreduce(v, "x", c), x, x_un),
         ("reduce_scatter",
-         lambda v, c: api.reduce_scatter(v.reshape(-1), "x", c), x),
+         lambda v, c: api.reduce_scatter(v.reshape(-1), "x", c), x, x_un),
         ("allgather",
-         lambda v, c: api.allgather(v.reshape(-1), "x", c), blocks),
+         lambda v, c: api.allgather(v.reshape(-1), "x", c), blocks,
+         blocks_un),
     ):
-        a = np.asarray(under(lambda v: fn(v, cfg))(arg))
-        b = np.asarray(under(lambda v: fn(v, ref))(arg))
-        np.testing.assert_array_equal(a, b), (name, algo)
+        for aligned, a_in in ((True, arg), (False, arg_un)):
+            assert kernels_in(lambda v: fn(v, cfg), a_in) == aligned, \
+                (name, algo, aligned)
+            a = np.asarray(under(lambda v: fn(v, cfg))(a_in))
+            b = np.asarray(under(lambda v: fn(v, ref))(a_in))
+            np.testing.assert_array_equal(a, b), (name, algo)
 
 # small-allreduce regime parity (fused falls back to the shmap small path)
 cfg_small = api.CollectiveConfig(backend="pallas_fused",
@@ -143,8 +163,9 @@ b = np.asarray(under(lambda v: api.allreduce(v, "x", ref_small))(x))
 np.testing.assert_array_equal(a, b)
 
 # tuple-axis case: the flattened ("pod","data") gradient axis
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
-xh = rng.randn(8, 512).astype(np.float32)
+from repro.launch.mesh import make_mesh
+mesh2 = make_mesh((2, 4), ("pod", "data"))
+xh = rng.randn(8, 8192).astype(np.float32)
 for algo in fused.ALGOS:
     cfg = api.CollectiveConfig(backend="pallas_fused", fused_algo=algo,
                                small_cutoff_bytes=0)
@@ -167,7 +188,7 @@ out = np.asarray(under(lambda v: api.gather(
 np.testing.assert_allclose(out[5], blocks.reshape(-1), rtol=1e-5)
 
 # dim-general fused RS/AG (the train-step ZeRO path)
-w = rng.randn(8, 64, 24).astype(np.float32)
+w = rng.randn(8, 64, 128).astype(np.float32)
 for dim in (0, 1):
     for algo in fused.ALGOS:
         full = w.sum(0)
@@ -187,10 +208,10 @@ for dim in (0, 1):
 
 # fused matmul+RS and AG+matmul vs unfused compositions
 xm = rng.randn(8, 64, 32).astype(np.float32)
-wm = jnp.asarray(rng.randn(32, 48).astype(np.float32))
+wm = jnp.asarray(rng.randn(32, 128).astype(np.float32))
 ysum = np.einsum("rmk,kn->mn", xm, np.asarray(wm))
-xb = rng.randn(8, 8, 32).astype(np.float32)
-fullg = xb.reshape(64, 32) @ np.asarray(wm)
+xb = rng.randn(8, 32, 32).astype(np.float32)
+fullg = xb.reshape(256, 32) @ np.asarray(wm)
 for algo in fused.ALGOS:
     got = np.asarray(under(
         lambda v: fused.matmul_reduce_scatter(v[0], wm, "x", algo)[None])(xm))
@@ -232,10 +253,10 @@ def test_rs_step_kernel_q_matches_ref(h):
     rq, rs_ = comp.quantize_wire(recv)
     for c in (0, 1):
         np.testing.assert_array_equal(
-            np.asarray(K.rs_step_kernel_q(buf, rq, rs_, c)),
+            np.asarray(K.rs_step_kernel_q(buf, rq, rs_, c, interpret=True)),
             np.asarray(R.rs_step_ref_q(buf, rq, rs_, c)))
         for cn in (0, 1):
-            o, q, s = K.rs_step_kernel_q(buf, rq, rs_, c, cn)
+            o, q, s = K.rs_step_kernel_q(buf, rq, rs_, c, cn, interpret=True)
             ro, rq2, rs2 = R.rs_step_ref_q(buf, rq, rs_, c, cn)
             np.testing.assert_array_equal(np.asarray(o), np.asarray(ro))
             np.testing.assert_array_equal(np.asarray(q), np.asarray(rq2))
@@ -250,7 +271,7 @@ def test_rs_step_kernel_q_nosend_small():
     rq, rs_ = comp.quantize_wire(jnp.asarray(rng.randn(h).astype(np.float32)))
     for c in (0, 1):
         np.testing.assert_array_equal(
-            np.asarray(K.rs_step_kernel_q(buf, rq, rs_, c)),
+            np.asarray(K.rs_step_kernel_q(buf, rq, rs_, c, interpret=True)),
             np.asarray(R.rs_step_ref_q(buf, rq, rs_, c)))
 
 
@@ -260,7 +281,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.collectives import api, shmap
 from repro.collectives import compression as comp
-from repro.compat import shard_map
+from jax import shard_map
 from repro.kernels import collectives as fused
 
 rng = np.random.RandomState(0)
@@ -268,12 +289,17 @@ devs = jax.devices()
 
 def under(fn, p, in_spec=P("x"), out_spec=P("x")):
     m = Mesh(np.asarray(devs[:p]), ("x",))
-    return jax.jit(shard_map(fn, mesh=m, in_specs=in_spec, out_specs=out_spec))
+    return jax.jit(shard_map(fn, mesh=m, in_specs=in_spec, out_specs=out_spec,
+                             check_vma=False))
 
 for p in (4, 8):
     for algo in ("bine", "recdoub"):
-        x = (rng.randn(p, p * 512) * 3).astype(np.float32)
-        blocks = (rng.randn(p, 512) * 3).astype(np.float32)
+        # rank blocks of whole int8 kernel tiles (8192 elements)
+        x = (rng.randn(p, p * 8192) * 3).astype(np.float32)
+        blocks = (rng.randn(p, 8192) * 3).astype(np.float32)
+        assert "pallas_call" in str(jax.make_jaxpr(under(
+            lambda v: fused.reduce_scatter_q(v.reshape(-1), "x", algo),
+            p))(x))
 
         # --- reduce_scatter: fused vs shmap must decode bit-identically
         a = np.asarray(under(
@@ -300,7 +326,7 @@ for p in (4, 8):
             g[0], blocks.reshape(-1),
             atol=np.abs(blocks).max() / 127.0 + 1e-7)
 
-        # --- unaligned per-rank block (blk % 256 != 0): the fused entry
+        # --- unaligned per-rank block (blk % 8192 != 0): the fused entry
         # falls back to the shmap codec path -- still bit-identical
         xr = (rng.randn(p, p * 192) * 3).astype(np.float32)
         a = np.asarray(under(
@@ -311,7 +337,7 @@ for p in (4, 8):
 
 # --- api dispatch: wire_dtype="int8" routes pallas_fused and bine to the
 # same bits; ring-family fused_algo and non-pow2 axes pass through to f32
-x8 = (rng.randn(8, 8 * 512) * 3).astype(np.float32)
+x8 = (rng.randn(8, 8 * 8192) * 3).astype(np.float32)
 cfg_f = api.CollectiveConfig(backend="pallas_fused", fused_algo="bine",
                              small_cutoff_bytes=0, wire_dtype="int8")
 cfg_s = api.CollectiveConfig(backend="bine", small_cutoff_bytes=0,
@@ -335,7 +361,7 @@ np.testing.assert_array_equal(a, b)
 # non-pow2 axis (p=6): the adapter schedules have no codec variant, so an
 # int8 wire silently runs the plain float32 path -- identical bits (the
 # ring family is the live non-pow2 plain path)
-x6 = (rng.randn(6, 6 * 512) * 3).astype(np.float32)
+x6 = (rng.randn(6, 6 * 1024) * 3).astype(np.float32)
 cfg6 = api.CollectiveConfig(backend="pallas_fused", fused_algo="ring",
                             small_cutoff_bytes=0, wire_dtype="int8")
 cfg6_f32 = api.CollectiveConfig(backend="pallas_fused", fused_algo="ring",
@@ -345,7 +371,7 @@ a = np.asarray(under(
 b = np.asarray(under(
     lambda v: api.reduce_scatter(v.reshape(-1), "x", cfg6_f32), 6)(x6))
 np.testing.assert_array_equal(a, b)
-bl6 = (rng.randn(6, 512) * 3).astype(np.float32)
+bl6 = (rng.randn(6, 1024) * 3).astype(np.float32)
 a = np.asarray(under(
     lambda v: api.allgather(v.reshape(-1), "x", cfg6), 6)(bl6))
 b = np.asarray(under(

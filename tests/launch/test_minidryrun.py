@@ -13,9 +13,10 @@ from repro.models.sharding import param_specs
 from repro.train.step import TrainConfig, make_train_step
 from repro.serve.engine import ServeConfig, make_serve_fns, cache_specs
 from repro.launch import hlo as H
-from repro.compat import set_mesh
+from jax import set_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("qwen3-32b"))
 key = jax.random.key(0)
 shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), key)

@@ -6,9 +6,10 @@ CODE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import base
 from repro.models import moe as M, sharding as sh
-from repro.compat import set_mesh
+from jax import set_mesh
 
-mesh = jax.make_mesh((1, 1, 8), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 1, 8), ("pod", "data", "model"))
 key = jax.random.key(0)
 
 for E, nb, K in ((8, 2, 2), (16, 1, 2), (8, 1, 1)):

@@ -11,14 +11,15 @@
 
 _SERVE_ZERO_RETRACE = r"""
 import jax
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base as cfgbase
 from repro.models import transformer as T
 from repro.obs import metrics
 from repro.serve.engine import ServeConfig, make_serve_fns, page_len
 from repro.serve.scheduler import ContinuousBatchingScheduler, poisson_trace
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = cfgbase.reduced(cfgbase.get_config("gemma3-4b"))
 S = page_len(cfg, 24, 8)
 
@@ -49,7 +50,7 @@ print("ZERO_RETRACE_OK", on)
 
 _TRAIN_BUCKET_REGISTRY = r"""
 import jax, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base
 from repro.core import traffic
 from repro.core.schedules import get_schedule
@@ -63,7 +64,8 @@ from repro.train.data import DataConfig, make_batch
 from repro.train.step import (TrainConfig, bucket_decisions, make_init_fns,
                               make_train_step)
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 cfg = base.reduced(base.get_config("phi4-mini-3.8b")).replace(dtype="float32")
 tcfg = TrainConfig(backend="bine", topology="lumi", bucket_bytes=1 << 18,
                    adamw=AdamWConfig(lr=3e-3, warmup_steps=1,

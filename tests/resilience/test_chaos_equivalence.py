@@ -12,7 +12,7 @@ out, because pages are computationally independent and RNG is keyed per
 
 CHAOS_EQUIV_CODE = r"""
 import jax, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base
 from repro.fleet import Fleet, FleetConfig
 from repro.models import transformer as T
@@ -21,7 +21,8 @@ from repro.resilience import (ChaosSchedule, FaultEvent, FleetSupervisor,
 from repro.serve.engine import ServeConfig, make_serve_fns
 from repro.serve.scheduler import poisson_trace
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = base.reduced(base.get_config("gemma3-4b"))
 S, MAX_NEW, SEED = 64, 6, 11
 params = jax.jit(lambda k: T.init_params(k, cfg))(jax.random.key(0))

@@ -14,7 +14,7 @@ fallback reduces in a deterministic order.
 ELASTIC_RESUME_CODE = r"""
 import jax, numpy as np
 from jax.sharding import Mesh
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base
 from repro.models import transformer as T
 from repro.optim.adamw import AdamWConfig

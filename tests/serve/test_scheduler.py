@@ -229,14 +229,15 @@ def test_per_request_latency_stats():
 
 EQUIV_CODE = r"""
 import jax, numpy as np
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs import base
 from repro.models import transformer as T
 from repro.serve.engine import ServeConfig, make_serve_fns
 from repro.serve.sampling import SamplingParams
 from repro.serve.scheduler import ContinuousBatchingScheduler, Request
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = base.reduced(base.get_config("gemma3-4b"))   # dense + 5:1 local:global
 S, MAX_NEW = 64, 6
 params = jax.jit(lambda k: T.init_params(k, cfg))(jax.random.key(0))

@@ -23,7 +23,7 @@ import jax, numpy as np
 from repro.configs import base
 from repro.models import transformer as T
 from repro.train.step import TrainConfig, make_train_step, make_init_fns
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.train.data import DataConfig, make_batch
 from repro.optim.adamw import AdamWConfig
 
@@ -38,7 +38,8 @@ TOL_BACKENDS = %s
 # table-driven default (usually one big bucket)
 BUCKET_SETTINGS = %s
 
-mesh = jax.make_mesh(MESH_SHAPE, ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(MESH_SHAPE, ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("phi4-mini-3.8b")).replace(dtype="float32")
 acfg = AdamWConfig(lr=3e-3, warmup_steps=1, total_steps=100)
 key = jax.random.key(0)
@@ -105,10 +106,11 @@ import jax, numpy as np
 from repro.configs import base
 from repro.models import transformer as T
 from repro.train.step import TrainConfig, make_train_step
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.launch import hlo, dryrun
 
-mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("qwen3-32b"))
 key = jax.random.key(0)
 params_shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), key)

@@ -42,11 +42,12 @@ from repro.configs import base
 from repro.models import transformer as T
 from repro.train.step import (TrainConfig, bucket_report, make_train_step,
                               make_init_fns)
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.train.data import DataConfig, make_batch
 from repro.optim.adamw import AdamWConfig
 
-mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("phi4-mini-3.8b")).replace(dtype="float32")
 acfg = AdamWConfig(lr=3e-3, warmup_steps=1, total_steps=100)
 key = jax.random.key(0)
@@ -139,11 +140,12 @@ import jax, numpy as np
 from repro.configs import base
 from repro.models import transformer as T
 from repro.train.step import TrainConfig, make_train_step, make_init_fns
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.train.data import DataConfig, make_batch
 from repro.optim.adamw import AdamWConfig
 
-mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("phi4-mini-3.8b")).replace(
     dtype="float32", n_layers=2)
 acfg = AdamWConfig(lr=1e-2, warmup_steps=10, total_steps=250)

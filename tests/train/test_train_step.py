@@ -7,11 +7,12 @@ import jax, numpy as np
 from repro.configs import base
 from repro.models import transformer as T
 from repro.train.step import TrainConfig, make_train_step, make_init_fns
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.train.data import DataConfig, make_batch
 from repro.optim.adamw import AdamWConfig
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = base.reduced(base.get_config("phi4-mini-3.8b"))
 acfg = AdamWConfig(lr=3e-3, warmup_steps=1, total_steps=100)
 key = jax.random.key(0)
@@ -78,11 +79,12 @@ def test_train_backends(subproc):
 BF16_OVERFLOW = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.train.step import (TrainConfig, _post_reduce_div, _rs_leaf,
                               _wire_cast)
 
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 p = 4
 tcfg = TrainConfig(backend="bine", dp_axes=("data",), wire_dtype="bfloat16")
 
@@ -122,3 +124,65 @@ print("BF16_OK")
 def test_bf16_wire_prescale_no_overflow(subproc):
     out = subproc(BF16_OVERFLOW, devices=4, timeout=600)
     assert "BF16_OK" in out
+
+
+TP_AUTO = r"""
+import jax, numpy as np
+from repro.configs import base
+from repro.models import transformer as T
+from repro.train.step import (TrainConfig, bucket_decisions, for_mesh,
+                              make_init_fns, make_train_step)
+from jax import set_mesh
+from repro.train.data import DataConfig, make_batch
+from repro.launch.mesh import make_mesh
+
+# a vocabulary large enough that the embedding bucket crosses the
+# payload at which the tpu_multipod table picks pallas_fused
+cfg = base.reduced(base.get_config("phi4-mini-3.8b")).replace(
+    vocab_size=32768)
+key = jax.random.key(0)
+shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), key)
+dcfg = DataConfig(global_batch=4, seq_len=32, vocab_size=cfg.vocab_size)
+tcfg = TrainConfig(backend="auto", dp_axes=("data",))
+
+def picks(tc, mesh):
+    step_fn, sh, _ = make_train_step(cfg, tc, mesh, shapes)
+    return {b for d in bucket_decisions(tc, sh["bucket_plan"])
+            for b in (d[0], d[2])}, step_fn, sh
+
+# model axis 1: the step is fully manual and auto may pick the kernels
+mesh1 = make_mesh((2, 1), ("data", "model"))
+assert for_mesh(tcfg, mesh1) is tcfg
+assert "pallas_fused" in picks(tcfg, mesh1)[0]
+
+# model axis 2: the step leaves "model" to GSPMD, where Mosaic kernels
+# cannot be partitioned -- auto runs bine there, and the step trains
+mesh2 = make_mesh((2, 2), ("data", "model"))
+tc2 = for_mesh(tcfg, mesh2)
+got, step_fn, sh = picks(tc2, mesh2)
+assert "pallas_fused" not in got and "bine" in got, got
+init_p, init_s = make_init_fns(cfg, tcfg, mesh2, shapes)
+with set_mesh(mesh2):
+    params = init_p(key)
+    state = init_s(params)
+    b = make_batch(dcfg, 0)
+    batch = {k: jax.device_put(v, sh["batch"][k]) for k, v in b.items()}
+    assert "pallas_call" not in str(jax.make_jaxpr(step_fn)(params, state,
+                                                           batch))
+    params, state, metrics = step_fn(params, state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+
+# an explicit pallas_fused with a model axis above 1 is refused at build
+try:
+    make_train_step(cfg, tcfg.replace(backend="pallas_fused"), mesh2, shapes)
+except ValueError as e:
+    assert "pallas_fused" in str(e)
+else:
+    raise AssertionError("pallas_fused with model=2 was not refused")
+print("TP_AUTO_OK")
+"""
+
+
+def test_auto_keeps_fused_kernels_out_of_tp_step(subproc):
+    out = subproc(TP_AUTO, devices=4, timeout=600)
+    assert "TP_AUTO_OK" in out

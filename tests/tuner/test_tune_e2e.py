@@ -46,9 +46,10 @@ from repro.models import transformer as T
 from repro.models.sharding import param_specs
 from repro.train.step import TrainConfig, make_train_step, bucket_report
 from repro.launch.dryrun import _opt_shapes
-from repro.compat import set_mesh
+from jax import set_mesh
 
-mesh = jax.make_mesh((4, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 1), ("data", "model"))
 cfg = base.reduced(base.get_config("qwen3-32b"))
 shapes = jax.eval_shape(lambda k: T.init_params(k, cfg), jax.random.key(0))
 tcfg = TrainConfig(backend="auto", tuning="measured", dp_axes=("data",),
