@@ -11,6 +11,10 @@ straggler monitor, and restart-on-failure.
 ``--layers N`` cuts depth only (every width stays as published), so a
 full-width model fits one chip's memory: phi4-mini-3.8b at one layer is
 about 715 M parameters, 11.4 GB at 16 bytes a parameter.
+
+Each step's batch put, step and loss fetch are ``train.put``,
+``train.step`` and ``train.fetch`` spans; ``jax.profiler.trace`` around
+:func:`main` records them on the device trace's clock.
 """
 
 from __future__ import annotations
@@ -26,6 +30,7 @@ from repro.configs import base as cfgbase
 from repro.launch.mesh import (dp_axes as mesh_dp_axes, enable_compile_cache,
                                mesh_from_arg)
 from repro.models import transformer as T
+from repro.obs.timeline import host_span
 from repro.optim.adamw import AdamWConfig
 from repro.train import checkpoint as ckpt
 from repro.train.data import DataConfig, Prefetcher, make_batch
@@ -118,15 +123,18 @@ def main(argv=None):
         pf = Prefetcher(dcfg, start_step=start)
         losses, gnorms, step_s = [], [], []
         try:
-            t_all = time.time()
+            t_all = time.perf_counter()
             for s in range(start, args.steps):
-                t0 = time.time()
-                _, b = pf.next()
-                batch = {k: jax.device_put(v, shardings["batch"][k])
-                         for k, v in b.items()}
-                params, state, metrics = step_fn(params, state, batch)
-                loss = float(metrics["loss"])
-                dt = time.time() - t0
+                t0 = time.perf_counter()
+                with host_span("train.put"):
+                    _, b = pf.next()
+                    batch = {k: jax.device_put(v, shardings["batch"][k])
+                             for k, v in b.items()}
+                with host_span("train.step"):
+                    params, state, metrics = step_fn(params, state, batch)
+                with host_span("train.fetch"):
+                    loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
                 if not math.isfinite(loss):
                     raise FloatingPointError(f"step {s}: loss {loss}")
                 losses.append(loss)
@@ -144,7 +152,7 @@ def main(argv=None):
             if cpr:
                 cpr.save(args.steps, {"params": params, "state": state},
                          block=True)
-            total = time.time() - t_all
+            total = time.perf_counter() - t_all
             print(f"[train] done: {args.steps - start} steps in {total:.1f}s "
                   f"({(args.steps - start) / max(total, 1e-9):.2f} it/s); "
                   f"stragglers flagged: {len(monitor.flagged)}")

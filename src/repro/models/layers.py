@@ -112,6 +112,7 @@ def _sdpa_chunk(q, k, v, qpos, kpos, window, scale):
     return o, m_safe, denom
 
 
+@jax.named_scope("attn")
 def attention(p, cfg, x, positions, window=None):
     """Causal (optionally windowed) GQA over full sequences.
 
@@ -429,6 +430,7 @@ def init_mlp(key, cfg, d_ff: Optional[int] = None) -> dict:
     }
 
 
+@jax.named_scope("mlp")
 def mlp(p, cfg, x):
     h = dense(x, p["wi"])
     gate = dense(x, p["wg"])

@@ -182,6 +182,7 @@ def _apply_block(p, cfg, block: Block, x, positions):
     return seq_sharded(x), aux
 
 
+@jax.named_scope("embed")
 def _embed_in(params, cfg, inputs):
     if cfg.frontend is not None and inputs.ndim == 3:
         x = L.dense(inputs, params["frontend_proj"])
@@ -217,11 +218,13 @@ def forward(params, cfg, inputs, positions=None) -> Tuple[jax.Array, jax.Array]:
         fn = jax.checkpoint(body) if cfg.remat else body
         (x, aux_total), _ = lax.scan(fn, (x, aux_total), seg_p)
 
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    head = shard(head, None, "model")
-    logits = jnp.einsum("btd,dv->btv", x, head)
-    logits = shard(logits, None, None, "model")
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        head = shard(head, None, "model")
+        logits = jnp.einsum("btd,dv->btv", x, head)
+        logits = shard(logits, None, None, "model")
     return logits, aux_total
 
 
@@ -230,17 +233,19 @@ def loss_fn(params, cfg, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
 
     Cross entropy in fp32 with z-loss; returns (loss, metrics)."""
     logits, aux = forward(params, cfg, batch["inputs"])
-    logits = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(
-        logits, batch["targets"][..., None].astype(jnp.int32), axis=-1)[..., 0]
-    nll = lse - tgt
-    mask = batch.get("mask")
-    if mask is None:
-        mask = jnp.ones(nll.shape, jnp.float32)
-    denom = jnp.maximum(mask.sum(), 1.0)
-    ce = (nll * mask).sum() / denom
-    zl = cfg.z_loss * ((lse * lse) * mask).sum() / denom
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(
+            logits, batch["targets"][..., None].astype(jnp.int32),
+            axis=-1)[..., 0]
+        nll = lse - tgt
+        mask = batch.get("mask")
+        if mask is None:
+            mask = jnp.ones(nll.shape, jnp.float32)
+        denom = jnp.maximum(mask.sum(), 1.0)
+        ce = (nll * mask).sum() / denom
+        zl = cfg.z_loss * ((lse * lse) * mask).sum() / denom
     al = cfg.aux_loss_weight * aux
     loss = ce + zl + al
     metrics = {"loss": loss, "ce": ce, "z_loss": zl, "aux_loss": al,
@@ -315,42 +320,48 @@ def _decode_attn(p, cfg, block: Block, x, cache, pos):
         positions = jnp.full((1,), pos, dtype=jnp.int32)
     q, k, v = L._qkv(p["attn"], cfg, L.rmsnorm(x, p["ln1"], cfg.norm_eps),
                      positions)
-    if per_slot:
-        # batched scatter, one row per slot (out-of-range slots — a full
-        # cache that ran past its page — drop the write, like the clamp-free
-        # one-hot select would)
-        rows = jnp.arange(B)
-        ck = cache["k"].at[rows, slot].set(
-            k[:, 0].astype(cache["k"].dtype), mode="drop")
-        cv = cache["v"].at[rows, slot].set(
-            v[:, 0].astype(cache["v"].dtype), mode="drop")
-    else:
-        ck = lax.dynamic_update_slice_in_dim(
-            cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-        cv = lax.dynamic_update_slice_in_dim(
-            cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+    with jax.named_scope("kv_write"):
+        if per_slot:
+            # batched scatter, one row per slot (out-of-range slots — a
+            # full cache that ran past its page — drop the write, like the
+            # clamp-free one-hot select would)
+            rows = jnp.arange(B)
+            ck = cache["k"].at[rows, slot].set(
+                k[:, 0].astype(cache["k"].dtype), mode="drop")
+            cv = cache["v"].at[rows, slot].set(
+                v[:, 0].astype(cache["v"].dtype), mode="drop")
+        else:
+            ck = lax.dynamic_update_slice_in_dim(
+                cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
+            cv = lax.dynamic_update_slice_in_dim(
+                cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = nh // nkv
-    qg = q.reshape(B, 1, nkv, g, hd)
-    s = jnp.einsum("btkgh,bskh->bkgs", qg.astype(jnp.float32),
-                   ck.astype(jnp.float32)) / math.sqrt(hd)
-    # cache slot s holds absolute position: s (no window) or ring-decoded
-    kpos = jnp.arange(W)[None, :] if per_slot else jnp.arange(W)
-    posb = pos[:, None] if per_slot else pos
-    slotb = slot[:, None] if per_slot else slot
-    if ring:
-        # ring slots hold positions pos-W+1..pos; valid if <= pos and fresh
-        age = (slotb - kpos) % W
-        abs_pos = posb - age
-        valid = (abs_pos >= 0) & (abs_pos <= posb) & (posb - abs_pos < block.window)
-    else:
-        valid = kpos <= posb
-        if block.window is not None:
-            valid &= (posb - kpos) < block.window
-    vmask = valid[:, None, None, :] if per_slot else valid[None, None, None, :]
-    s = jnp.where(vmask, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskh->bkgh", w, cv.astype(jnp.float32))
+    with jax.named_scope("decode_attn"):
+        qg = q.reshape(B, 1, nkv, g, hd)
+        s = jnp.einsum("btkgh,bskh->bkgs", qg.astype(jnp.float32),
+                       ck.astype(jnp.float32)) / math.sqrt(hd)
+        # cache slot s holds absolute position: s (no window) or
+        # ring-decoded
+        kpos = jnp.arange(W)[None, :] if per_slot else jnp.arange(W)
+        posb = pos[:, None] if per_slot else pos
+        slotb = slot[:, None] if per_slot else slot
+        if ring:
+            # ring slots hold positions pos-W+1..pos; valid if <= pos and
+            # fresh
+            age = (slotb - kpos) % W
+            abs_pos = posb - age
+            valid = ((abs_pos >= 0) & (abs_pos <= posb)
+                     & (posb - abs_pos < block.window))
+        else:
+            valid = kpos <= posb
+            if block.window is not None:
+                valid &= (posb - kpos) < block.window
+        vmask = (valid[:, None, None, :] if per_slot
+                 else valid[None, None, None, :])
+        s = jnp.where(vmask, s, -jnp.inf)
+        w = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgs,bskh->bkgh", w, cv.astype(jnp.float32))
     o = o.reshape(B, 1, nh * hd).astype(x.dtype)
     out = L.dense(o, p["attn"]["wo"])
     return out, {"k": ck, "v": cv}
@@ -412,9 +423,11 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, dic
 
         x, cs = lax.scan(body, x, (seg_p, seg_c))
         new_segs.append(cs)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("btd,dv->btv", x, head)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("btd,dv->btv", x, head)
     adv = 1 if active is None else active.astype(jnp.int32)
     return logits, {"segments": new_segs, "pos": pos + adv}
 
@@ -468,9 +481,11 @@ def prefill(params, cfg, inputs, length=None) -> Tuple[jax.Array, dict]:
     else:
         xl = lax.dynamic_slice_in_dim(x, jnp.maximum(length - 1, 0), 1, axis=1)
         pos_out = jnp.asarray(length, jnp.int32)
-    x = L.rmsnorm(xl, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("btd,dv->btv", x, head)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(xl, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("btd,dv->btv", x, head)
     return logits, {"segments": segs, "pos": pos_out}
 
 

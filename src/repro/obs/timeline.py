@@ -1,17 +1,21 @@
-"""Trace-timeline export (Chrome trace / Perfetto) + Prometheus text.
+"""Trace-timeline export (Chrome trace / Perfetto) + Prometheus text,
+and the program's host spans on the profiler's clock.
 
-The runtime layers append events into a :class:`Timeline` — train steps
-and fleet ticks as **spans**, chaos faults and replica drain/respawn as
-**instants** — and :func:`to_chrome_trace` renders them in the Chrome
-Trace Event format (``{"traceEvents": [...]}``, ``ph="X"`` complete
-spans and ``ph="i"`` instants, microsecond timestamps), which loads
-directly in ``ui.perfetto.dev`` or ``chrome://tracing``.
+The runtime layers append events into a :class:`Timeline` — train steps,
+requests' queue waits and fleet ticks as **spans**, compiles, chaos
+faults and replica drain/respawn as **instants** — and
+:func:`to_chrome_trace` renders them in the Chrome Trace Event format
+(``{"traceEvents": [...]}``, ``ph="X"`` complete spans and ``ph="i"``
+instants, microsecond timestamps), which loads directly in
+``ui.perfetto.dev`` or ``chrome://tracing``.
 
 Two time bases coexist by design:
 
-  * **train** events are wall-clock (``time.time()`` seconds at the call
-    site, rendered as µs since the timeline's first event);
-  * **fleet/serve** events use the fleet's *virtual integer tick clock*
+  * **real time** (lanes :data:`HOST_CLOCK_LANES`: ``train``, ``serve``,
+    ``runtime``): ``time.perf_counter()`` at the call site, the clock a
+    benchmark times its window with, rendered as µs since the lane's
+    first event;
+  * **fleet/chaos** events use the fleet's *virtual integer tick clock*
     (1 tick = 1 µs in the trace) — deterministic replays produce
     byte-identical timelines, and chaos instants land exactly on the
     tick that armed them.
@@ -19,6 +23,22 @@ Two time bases coexist by design:
 Each producer gets its own ``pid`` lane ("train", "fleet", …) so the two
 clocks never share a track and the viewer shows them as separate
 processes.
+
+Per-step work is not logged here: :func:`host_span` opens a
+``jax.profiler.TraceAnnotation``, which costs nothing lasting when no
+profiler runs and lands on the device trace's clock when one does
+(``jax.profiler.trace`` around a run).  The program's spans:
+
+  * ``serve.admit`` (an insert and its first token's sample),
+    ``serve.decode``, ``serve.sample``, ``serve.retire``
+    (``serve/scheduler.py``);
+  * ``train.put``, ``train.step``, ``train.fetch`` (``train/runtime.py``
+    and the ``launch/train.py`` loop).
+
+Timeline records on the host clock: ``serve.queue`` (one per admitted
+request, from ``submit()`` to the start of its insert), ``train_step``
+(one per ``TrainLoop`` step) and the compile counter's ``jax.compile`` /
+``jax.cache_load`` instants (:mod:`repro.obs.compiles`).
 
 :func:`export_prom` renders a :class:`~repro.obs.metrics.Registry` in
 the Prometheus text exposition format (counters/gauges as samples,
@@ -32,10 +52,21 @@ import json
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+import jax
+
 from repro.obs import metrics
 
 #: pid lanes in the trace, one per producer clock
-LANES = ("train", "fleet", "serve", "chaos", "bench")
+LANES = ("train", "fleet", "serve", "chaos", "bench", "runtime")
+#: lanes stamped with ``time.perf_counter()``, rebased to their first
+#: event
+HOST_CLOCK_LANES = ("train", "serve", "runtime")
+
+
+def host_span(name: str):
+    """A span on the profiler's clock around a ``with`` block: recorded
+    in the trace when a profiler runs, nowhere otherwise."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 @dataclass
@@ -97,14 +128,14 @@ class Timeline:
 def to_chrome_trace(tl: Timeline) -> dict:
     """Render as a Chrome Trace Event JSON object.
 
-    Wall-clock lanes are rebased so the run's first event sits at ts=0
-    (Perfetto dislikes epoch-scale microsecond offsets); virtual-tick
+    Host-clock lanes are rebased so each lane's first event sits at ts=0
+    (``perf_counter`` counts from an arbitrary origin); virtual-tick
     lanes are already small integers and pass through unchanged.
     """
     # rebase each lane independently: lanes are separate clocks
     base: Dict[str, float] = {}
     for e in tl.events:
-        if e.ts_us >= 1e12:  # epoch-scale wall clock
+        if e.lane in HOST_CLOCK_LANES:
             base[e.lane] = min(base.get(e.lane, e.ts_us), e.ts_us)
     trace: List[dict] = []
     pids = {lane: i + 1 for i, lane in enumerate(LANES)}

@@ -27,6 +27,12 @@ matters.  Time is virtual: one scheduler iteration = one time unit, and
 request arrivals (e.g. from :func:`poisson_trace`) are compared against
 that clock, which keeps every run exactly reproducible.
 
+What it records in real time (``repro.obs.timeline``): each admitted
+request's ``serve.queue`` span, from ``submit()`` to the start of its
+insert, on ``perf_counter``; and, for a profiler only, ``serve.admit``,
+``serve.decode``, ``serve.sample`` and ``serve.retire`` around each
+iteration's phases.
+
 The equivalence property tests/serve/test_scheduler.py locks in: because
 pages are computationally independent and RNG is per-request, a request's
 output stream is identical whether it runs alone in a 1-page pool or
@@ -36,6 +42,7 @@ interleaved with arbitrary other traffic.
 from __future__ import annotations
 
 import heapq
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -43,6 +50,8 @@ import jax
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
+from repro.obs import timeline as obs_timeline
+from repro.obs.timeline import host_span
 from repro.serve import sampling as S
 from repro.serve.kvcache import SlotAllocator
 
@@ -61,6 +70,8 @@ class Request:
     finished: bool = False
     finish_reason: Optional[str] = None   # "eos" | "length"
     admitted_at: Optional[float] = None
+    #: host clock (``time.perf_counter()`` seconds) of the last submit()
+    queued_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     #: routing affinity: requests sharing a session hash to the same
@@ -132,18 +143,20 @@ class ContinuousBatchingScheduler:
                 f"request {req.rid}: top_p={req.sampling.top_p} differs from "
                 f"the pool sampler's top_p={self.top_p} (top_p selects the "
                 f"compiled sampler's nucleus path, so it is pool-global)")
+        req.queued_at = time.perf_counter()
         heapq.heappush(self._waiting, (req.arrival, req.rid, req))
 
     # -- internals ----------------------------------------------------------
 
     def _sample_one(self, logits, req: Request) -> int:
-        tok = self.sampler(
-            logits,
-            np.asarray([req.sampling.temperature], np.float32),
-            np.asarray([req.rid], np.int32),
-            np.asarray([len(req.generated)], np.int32),
-            self.key)
-        return int(np.asarray(tok)[0])
+        with host_span("serve.sample"):
+            tok = self.sampler(
+                logits,
+                np.asarray([req.sampling.temperature], np.float32),
+                np.asarray([req.rid], np.int32),
+                np.asarray([len(req.generated)], np.int32),
+                self.key)
+            return int(np.asarray(tok)[0])
 
     def _retire(self, slot: int, req: Request, reason: str) -> None:
         req.finished = True
@@ -188,15 +201,20 @@ class ContinuousBatchingScheduler:
             slot = self.alloc.acquire()
             padded = np.zeros((1, self.max_seq_len), np.int32)
             padded[0, :len(req.prompt)] = req.prompt
-            logits, self.pool = self.fns.insert(
-                self.params, self.pool, padded,
-                np.int32(len(req.prompt)), np.int32(slot))
-            req.admitted_at = self.clock
-            self._running[slot] = req
-            self._temps[slot] = req.sampling.temperature
-            self._rids[slot] = req.rid
-            self._active[slot] = 1
-            self._record(slot, req, self._sample_one(logits, req))
+            t = time.perf_counter()
+            obs_timeline.get_timeline().span(
+                "serve.queue", "serve", req.queued_at * 1e6,
+                (t - req.queued_at) * 1e6, rid=req.rid)
+            with host_span("serve.admit"):
+                logits, self.pool = self.fns.insert(
+                    self.params, self.pool, padded,
+                    np.int32(len(req.prompt)), np.int32(slot))
+                req.admitted_at = self.clock
+                self._running[slot] = req
+                self._temps[slot] = req.sampling.temperature
+                self._rids[slot] = req.rid
+                self._active[slot] = 1
+                self._record(slot, req, self._sample_one(logits, req))
             admitted += 1
         return admitted
 
@@ -212,13 +230,16 @@ class ContinuousBatchingScheduler:
             return bool(self._waiting)
         for slot, req in self._running.items():
             self._steps[slot] = len(req.generated)
-        logits, self.pool = self.fns.decode_slots(
-            self.params, self.pool, self._next_tok, self._active)
-        toks = np.asarray(self.sampler(
-            logits, self._temps, self._rids, self._steps, self.key))
+        with host_span("serve.decode"):
+            logits, self.pool = self.fns.decode_slots(
+                self.params, self.pool, self._next_tok, self._active)
+        with host_span("serve.sample"):
+            toks = np.asarray(self.sampler(
+                logits, self._temps, self._rids, self._steps, self.key))
         self.alloc.tick()
-        for slot, req in list(self._running.items()):
-            self._record(slot, req, int(toks[slot]))
+        with host_span("serve.retire"):
+            for slot, req in list(self._running.items()):
+                self._record(slot, req, int(toks[slot]))
         self.clock += 1.0
         return bool(self._running or self._waiting)
 

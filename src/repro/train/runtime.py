@@ -13,6 +13,10 @@ actually exercised by tests:
     layout for the new n_dp, and reshard the restored state;
   * ``StragglerMonitor`` — per-step wall-time EWMA; flags outliers (on a
     real pod this triggers hot-spare swap; here it feeds metrics/logs).
+
+Step times are ``time.perf_counter()`` readings; each step's put, step
+and fetch are ``train.put``/``train.step``/``train.fetch`` spans for a
+profiler (``repro.obs.timeline.host_span``).
 """
 
 from __future__ import annotations
@@ -26,6 +30,7 @@ import numpy as np
 
 from repro.obs import metrics as obs_metrics
 from repro.obs import timeline as obs_timeline
+from repro.obs.timeline import host_span
 from repro.train import checkpoint as ckpt
 
 
@@ -115,11 +120,14 @@ class TrainLoop:
         while s < self.cfg.total_steps:
             try:
                 self.injector.check(s)
-                t0 = time.time()
-                batch = put_batch(data_at(s))
-                params, state, metrics = step_fn(params, state, batch)
-                loss = float(metrics["loss"])
-                dt = time.time() - t0
+                t0 = time.perf_counter()
+                with host_span("train.put"):
+                    batch = put_batch(data_at(s))
+                with host_span("train.step"):
+                    params, state, metrics = step_fn(params, state, batch)
+                with host_span("train.fetch"):
+                    loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
                 self.monitor.observe(s, dt)
                 self.history.append({"step": s, "loss": loss, "dt": dt,
                                      "restarts": self.restarts,

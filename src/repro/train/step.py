@@ -48,6 +48,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+import repro.obs  # noqa: F401  (its compile counter sees the step compile)
 from repro.collectives import shmap
 from repro.models import transformer as T
 from repro.models.sharding import constrain_params, param_specs
@@ -684,47 +685,50 @@ def make_train_step(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
                 g_sh[i] = _rs_leaf(tcfg, flat_gr[i], -1, n_dp).astype(
                     jnp.float32) / post
             for bucket, (rs_b, rs_w, _, _) in zip(plan.buckets, decisions):
-                v = buckets.pack_bucket(
-                    bucket,
-                    [_bucket_wire_cast(rs_w, flat_gr[s.index], n_dp)
-                     for s in bucket.slots], n_dp)
-                if rs_w == "int8":
-                    # error feedback: quantization error this rank's wire
-                    # codec will commit lands in the residual and rides
-                    # into next step's gradient (wire_int8 = the SAME
-                    # codec, so the first re-encode on the wire is
-                    # lossless and the residual is exact for it)
-                    from repro.collectives import compression as comp
-                    sent, res = comp.ef_compress(
-                        v, state["ef"][str(bucket.bid)][0],
-                        codec="wire_int8")
-                    new_ef[str(bucket.bid)] = res[None]
-                    row = _rs_bucket_q(rs_b, tcfg.dp_axes, sent)
-                else:
-                    row = _rs_bucket(tcfg, v, backend=rs_b)
-                row = row.astype(jnp.float32) / _bucket_post(rs_w, n_dp)
-                for s, view in zip(bucket.slots,
-                                   buckets.shard_views(bucket, row, n_dp)):
-                    g_sh[s.index] = view
+                with jax.named_scope(f"rs/b{bucket.bid}"):
+                    v = buckets.pack_bucket(
+                        bucket,
+                        [_bucket_wire_cast(rs_w, flat_gr[s.index], n_dp)
+                         for s in bucket.slots], n_dp)
+                    if rs_w == "int8":
+                        # error feedback: quantization error this rank's
+                        # wire codec will commit lands in the residual and
+                        # rides into next step's gradient (wire_int8 = the
+                        # SAME codec, so the first re-encode on the wire is
+                        # lossless and the residual is exact for it)
+                        from repro.collectives import compression as comp
+                        sent, res = comp.ef_compress(
+                            v, state["ef"][str(bucket.bid)][0],
+                            codec="wire_int8")
+                        new_ef[str(bucket.bid)] = res[None]
+                        row = _rs_bucket_q(rs_b, tcfg.dp_axes, sent)
+                    else:
+                        row = _rs_bucket(tcfg, v, backend=rs_b)
+                    row = row.astype(jnp.float32) / _bucket_post(rs_w, n_dp)
+                    for s, view in zip(bucket.slots, buckets.shard_views(
+                            bucket, row, n_dp)):
+                        g_sh[s.index] = view
 
         # ---- grad-norm + metrics: ONE fused small allreduce ----
         # (was 6 scalar allreduces: 5 metrics + the grad-norm square)
-        sq_shard = sum(jnp.sum(jnp.square(g)) for g, zd in zip(
-            g_sh, flat_zd) if zd >= 0)
-        sq_repl = sum(jnp.sum(jnp.square(g)) for g, zd in zip(
-            g_sh, flat_zd) if zd < 0)
-        mkeys = sorted(metrics)
-        stacked = jnp.stack(
-            [jnp.asarray(sq_shard, jnp.float32)]
-            + [jnp.asarray(metrics[k], jnp.float32) for k in mkeys])
-        red = _small_allreduce(tcfg, stacked)
-        gnorm = jnp.sqrt(red[0] + sq_repl)
-        scale = jnp.minimum(1.0, tcfg.clip_norm / (gnorm + 1e-9)) \
-            if tcfg.clip_norm > 0 else jnp.ones(())
+        with jax.named_scope("grad_norm"):
+            sq_shard = sum(jnp.sum(jnp.square(g)) for g, zd in zip(
+                g_sh, flat_zd) if zd >= 0)
+            sq_repl = sum(jnp.sum(jnp.square(g)) for g, zd in zip(
+                g_sh, flat_zd) if zd < 0)
+            mkeys = sorted(metrics)
+            stacked = jnp.stack(
+                [jnp.asarray(sq_shard, jnp.float32)]
+                + [jnp.asarray(metrics[k], jnp.float32) for k in mkeys])
+            red = _small_allreduce(tcfg, stacked)
+            gnorm = jnp.sqrt(red[0] + sq_repl)
+            scale = jnp.minimum(1.0, tcfg.clip_norm / (gnorm + 1e-9)) \
+                if tcfg.clip_norm > 0 else jnp.ones(())
 
         # ---- sharded AdamW + parameter allgather ----
         lr = lr_at(tcfg.adamw, step)
 
+        @jax.named_scope("adamw")
         def upd(i):
             new_master, st2 = adamw_update_leaf(
                 tcfg.adamw, flat_opt[i], g_sh[i] * scale, step, lr)
@@ -747,19 +751,22 @@ def make_train_step(model_cfg, tcfg: TrainConfig, mesh, params_shapes):
                 for s in bucket.slots:
                     master, new_opt[s.index] = upd(s.index)
                     masters.append(master)
-                packed = buckets.pack_shards(bucket, masters)
-                if ag_w == "int8":
-                    # int8 param allgather: quantization error does NOT
-                    # compound — every step re-derives the wire value from
-                    # the exact f32 master, and all ranks decode the same
-                    # bits (quantize-once / move / dequantize-once)
-                    full = _ag_bucket_q(ag_b, tcfg.dp_axes, packed).astype(
-                        jnp.dtype(bucket.dtype))
-                else:
-                    full = _ag_bucket(tcfg, packed, backend=ag_b)
-                for s, leaf in zip(bucket.slots,
-                                   buckets.unpack_bucket(bucket, full, n_dp)):
-                    new_p[s.index] = leaf
+                with jax.named_scope(f"ag/b{bucket.bid}"):
+                    packed = buckets.pack_shards(bucket, masters)
+                    if ag_w == "int8":
+                        # int8 param allgather: quantization error does NOT
+                        # compound — every step re-derives the wire value
+                        # from the exact f32 master, and all ranks decode
+                        # the same bits (quantize-once / move /
+                        # dequantize-once)
+                        full = _ag_bucket_q(
+                            ag_b, tcfg.dp_axes, packed).astype(
+                                jnp.dtype(bucket.dtype))
+                    else:
+                        full = _ag_bucket(tcfg, packed, backend=ag_b)
+                    for s, leaf in zip(bucket.slots, buckets.unpack_bucket(
+                            bucket, full, n_dp)):
+                        new_p[s.index] = leaf
         new_params = jax.tree.unflatten(treedef, new_p)
         new_opt = jax.tree.unflatten(treedef, new_opt)
         new_params = constrain_params(model_cfg, new_params)

@@ -3,6 +3,9 @@ must land their spans/counters in the default registry and timeline —
 the acceptance run's "timeline contains train-step, tick and chaos-event
 spans" invariant, testable without devices."""
 
+import math
+import time
+
 import numpy as np
 
 from repro.obs import metrics
@@ -38,7 +41,9 @@ def test_train_loop_records_step_histogram_and_spans(
     loop = TrainLoop(TrainLoopConfig(total_steps=2, ckpt_every=100,
                                      ckpt_dir=str(tmp_path)),
                      _ToyBuilder())
+    t0 = time.perf_counter()
     loop.run(key=None)
+    t1 = time.perf_counter()
     hist = fresh_registry.histograms[
         ("train_step_seconds", (("shrink", "0"),))]
     assert hist.count == 2
@@ -46,6 +51,12 @@ def test_train_loop_records_step_histogram_and_spans(
     assert len(spans) == 2
     assert all(e.lane == "train" and e.dur_us is not None for e in spans)
     assert spans[0].args["step"] == 0 and spans[1].args["step"] == 1
+    # stamped on perf_counter, the clock a benchmark times its window with
+    assert t0 * 1e6 <= spans[0].ts_us
+    assert spans[0].ts_us + spans[0].dur_us <= spans[1].ts_us
+    assert spans[1].ts_us + spans[1].dur_us <= t1 * 1e6
+    for h, e in zip(loop.history, spans):
+        assert math.isclose(h["dt"], e.dur_us * 1e-6, rel_tol=1e-9)
 
 
 def test_train_loop_obs_disabled_records_nothing(

@@ -11,7 +11,7 @@ from repro.obs.timeline import (LANES, Timeline, dump_chrome_trace,
 
 def _sample_tl():
     tl = Timeline()
-    # train lane: epoch-scale wall-clock microseconds
+    # train lane: host-clock microseconds
     t0 = 1.7e15
     tl.span("train_step", "train", t0, 1500.0, step=0, loss=2.5)
     tl.span("train_step", "train", t0 + 2000.0, 1400.0, step=1)
@@ -40,6 +40,39 @@ def test_wall_clock_lane_rebased_virtual_lane_untouched():
     assert train[1]["ts"] == 2000.0
     fleet = [r for r in trace["traceEvents"] if r["name"] == "fleet_tick"]
     assert fleet[0]["ts"] == 4.0          # tick clock passes through
+
+
+def test_host_clock_lanes_rebased_at_any_scale():
+    """``perf_counter`` starts anywhere: every host-clock lane is rebased
+    to its own first event, and the tick lanes never are."""
+    tl = Timeline()
+    tl.span("serve.queue", "serve", 5.0e8, 300.0, rid=0)
+    tl.instant("jax.compile", "runtime", 5.0e8 + 40.0, duration_s=0.1)
+    tl.span("train_step", "train", 3.0, 2.0)
+    tl.span("fleet_tick", "fleet", 2.0e12, 1.0)
+    rows = {r["name"]: r for r in to_chrome_trace(tl)["traceEvents"]
+            if r.get("ph") in ("X", "i")}
+    assert rows["serve.queue"]["ts"] == 0.0
+    assert rows["jax.compile"]["ts"] == 0.0     # its own lane's first
+    assert rows["train_step"]["ts"] == 0.0
+    assert rows["fleet_tick"]["ts"] == 2.0e12
+
+
+def test_host_span_lands_in_the_profiler_trace(tmp_path):
+    """``host_span`` writes a ``TraceAnnotation``: inside a profiler
+    session it is an event of the trace's host plane."""
+    import jax
+    from repro.obs.timeline import host_span
+    with jax.profiler.trace(str(tmp_path)):
+        with host_span("serve.decode"):
+            jax.numpy.ones(4).block_until_ready()
+    with host_span("serve.decode"):        # no profiler: records nothing
+        pass
+    (pb,) = tmp_path.glob("**/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(pb))
+    names = [e.name for p in pd.planes if p.name.startswith("/host")
+             for line in p.lines for e in line.events]
+    assert names.count("serve.decode") == 1
 
 
 def test_lanes_get_distinct_pids_with_metadata():

@@ -113,6 +113,55 @@ def test_fake_engine_eos_retirement():
     assert req2.generated == [9] and req2.finish_reason == "eos"
 
 
+def test_queue_span_per_admitted_request_on_the_host_clock(monkeypatch):
+    """One ``serve.queue`` span per admitted request, on the scheduler's
+    host clock (``perf_counter``): it starts at ``submit()`` and lasts
+    until the request's insert starts."""
+    from types import SimpleNamespace
+    from repro.obs import metrics, timeline
+    from repro.serve import scheduler as sched_mod
+
+    ticks = iter(range(1000, 100000, 7))
+    clock = SimpleNamespace(now=None)
+
+    def perf_counter():
+        clock.now = float(next(ticks))
+        return clock.now
+
+    monkeypatch.setattr(sched_mod, "time",
+                        SimpleNamespace(perf_counter=perf_counter))
+    tl = timeline.Timeline()
+    monkeypatch.setattr(timeline, "_TIMELINE", tl)
+    prev = metrics.set_enabled(True)
+    try:
+        sched = _fake_sched(n_slots=1)
+        insert_start = {}
+        fake_insert = sched.fns.insert
+
+        def insert(params, pool, tokens, length, slot):
+            insert_start[int(length)] = clock.now
+            return fake_insert(params, pool, tokens, length, slot)
+
+        sched.fns.insert = insert
+        reqs = [Request(rid=i, prompt=np.zeros(L, np.int32),
+                        max_new_tokens=3) for i, L in enumerate((3, 5, 8))]
+        for r in reqs:
+            sched.submit(r)
+        submitted = {r.rid: r.queued_at for r in reqs}
+        sched.run()
+    finally:
+        metrics.set_enabled(prev)
+    spans = [e for e in tl.events if e.name == "serve.queue"]
+    assert [e.args["rid"] for e in spans] == [0, 1, 2]
+    for e, r in zip(spans, reqs):
+        assert e.lane == "serve"
+        assert e.ts_us == submitted[r.rid] * 1e6
+        assert e.ts_us + e.dur_us == insert_start[len(r.prompt)] * 1e6
+    # one slot: each request's insert starts after the one before it
+    ends = [e.ts_us + e.dur_us for e in spans]
+    assert ends == sorted(ends) and len(set(ends)) == 3
+
+
 def test_submit_validation():
     sched = _fake_sched(n_slots=1, max_seq_len=16)
     with pytest.raises(ValueError, match="exceeds page size"):
