@@ -300,16 +300,22 @@ def init_decode_state(cfg, B: int, S_len: int) -> dict:
     return {"segments": segs, "pos": jnp.zeros((), jnp.int32)}
 
 
-def _decode_attn(p, cfg, block: Block, x, cache, pos):
+def _decode_attn(p, cfg, block: Block, x, pool, layer, pos):
     """One-token windowed/full attention against a (possibly ring) cache.
+
+    ``pool`` is the segment's stacked cache (``k``/``v`` of shape
+    ``[n_layers, B, W, n_kv, hd]``) and ``layer`` the (traced) index of
+    this layer in it.  The new K/V row of each slot is written into the
+    pool in place and attention reads the layer's slab back out of it, so
+    a decode step moves one row a slot per layer, not the whole pool.
 
     ``pos`` is a scalar (legacy fixed-batch decode: every sequence at the
     same position) or a ``[B]`` vector (continuous-batching pool: each slot
     at its own position).  The vector path writes the new K/V with a
-    per-slot one-hot select instead of ``dynamic_update_slice`` — identical
+    batched scatter instead of ``dynamic_update_slice`` — identical
     values, batched indices.
     """
-    W = cache["k"].shape[1]
+    W = pool["k"].shape[2]
     B = x.shape[0]
     ring = block.window is not None and block.window <= W
     per_slot = jnp.ndim(pos) > 0
@@ -323,18 +329,19 @@ def _decode_attn(p, cfg, block: Block, x, cache, pos):
     with jax.named_scope("kv_write"):
         if per_slot:
             # batched scatter, one row per slot (out-of-range slots — a
-            # full cache that ran past its page — drop the write, like the
-            # clamp-free one-hot select would)
+            # full cache that ran past its page — drop the write)
             rows = jnp.arange(B)
-            ck = cache["k"].at[rows, slot].set(
-                k[:, 0].astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[rows, slot].set(
-                v[:, 0].astype(cache["v"].dtype), mode="drop")
+
+            def put(dst, new):
+                return dst.at[layer, rows, slot].set(
+                    new[:, 0].astype(dst.dtype), mode="drop")
         else:
-            ck = lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), slot, axis=1)
-            cv = lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), slot, axis=1)
+            def put(dst, new):
+                return lax.dynamic_update_slice(
+                    dst, new[None].astype(dst.dtype), (layer, 0, slot, 0, 0))
+        pool = {"k": put(pool["k"], k), "v": put(pool["v"], v)}
+    ck = lax.dynamic_index_in_dim(pool["k"], layer, keepdims=False)
+    cv = lax.dynamic_index_in_dim(pool["v"], layer, keepdims=False)
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     g = nh // nkv
     with jax.named_scope("decode_attn"):
@@ -364,12 +371,16 @@ def _decode_attn(p, cfg, block: Block, x, cache, pos):
         o = jnp.einsum("bkgs,bskh->bkgh", w, cv.astype(jnp.float32))
     o = o.reshape(B, 1, nh * hd).astype(x.dtype)
     out = L.dense(o, p["attn"]["wo"])
-    return out, {"k": ck, "v": cv}
+    return out, pool
 
 
-def _decode_block(p, cfg, block: Block, x, cache, pos):
+def _decode_block(p, cfg, block: Block, x, cache, pos, layer=None):
+    """One layer's decode step.  For attention-family blocks ``cache`` is
+    the segment's stacked K/V pool and ``layer`` this layer's index in it
+    (the pool comes back with the layer's rows written); recurrent blocks
+    take and return their own layer's state."""
     if block.kind in ("attn", "moe", "shared_attn"):
-        h, cache = _decode_attn(p, cfg, block, x, cache, pos)
+        h, cache = _decode_attn(p, cfg, block, x, cache, layer, pos)
         x = x + h
         y = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if block.kind == "moe":
@@ -404,6 +415,13 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, dic
     ``active`` mask (``[B]`` in {0,1}) only active slots advance their
     position — retired slots stay frozen until ``insert`` recycles them.
 
+    The K/V pool is updated in place: each attention segment's stacked
+    ``[n_layers, B, W, n_kv, hd]`` caches ride the layer loop as its carry
+    and every layer writes one row a slot into them, so with the pool
+    donated the step neither copies the pool nor rewrites whole slabs.
+    The layout is unchanged.  Recurrent segments, whose states are O(1)
+    in the sequence, scan them as per-layer ``xs``.
+
     Returns (logits [B,1,V], new_state)."""
     pos = state["pos"]
     x = _embed_in(params, cfg, tokens)
@@ -411,18 +429,22 @@ def decode_step(params, cfg, state, tokens, active=None) -> Tuple[jax.Array, dic
     for (block, n), seg_p, seg_c in zip(
             segments(cfg), params["segments"], state["segments"]):
         if block.kind == "shared_attn":
-            x, c = _decode_block(params["shared"], cfg, block, x,
-                                 jax.tree.map(lambda a: a[0], seg_c), pos)
-            new_segs.append(jax.tree.map(lambda a: a[None], c))
-            continue
+            x, seg_c = _decode_block(params["shared"], cfg, block, x, seg_c,
+                                     pos, layer=0)
+        elif block.kind in ("attn", "moe"):
+            def body(carry, lp_i):
+                (h, pool), (lp, i) = carry, lp_i
+                return _decode_block(lp, cfg, block, h, pool, pos, i), None
 
-        def body(h, pc):
-            lp, lc = pc
-            h, c = _decode_block(lp, cfg, block, h, lc, pos)
-            return h, c
+            (x, seg_c), _ = lax.scan(
+                body, (x, seg_c), (seg_p, jnp.arange(n, dtype=jnp.int32)))
+        else:
+            def body(h, pc):
+                lp, lc = pc
+                return _decode_block(lp, cfg, block, h, lc, pos)
 
-        x, cs = lax.scan(body, x, (seg_p, seg_c))
-        new_segs.append(cs)
+            x, seg_c = lax.scan(body, x, (seg_p, seg_c))
+        new_segs.append(seg_c)
     with jax.named_scope("head"):
         x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings

@@ -6,11 +6,14 @@ entire cache — KV runs for attention layers, ring buffers bounded by the
 window for sliding-window layers, so ``gemma3``'s 5:1 local:global
 pattern never holds more than ``window`` positions per local layer.  A
 per-slot ``pos`` vector (``[n_slots] int32``) replaces the legacy scalar
-position so every page advances independently.  (The pool pytree carries
-whatever ``init_decode_state`` defines — recurrent SSM/xLSTM states
-included — but only attention-only archs can be *served* through it; see
-``engine.pool_supported`` for why MoE and recurrent blocks are gated to
-the legacy fixed-batch path.)
+position so every page advances independently.  Each attention
+segment's pages are stacked as ``[n_layers, n_slots, W, n_kv, hd]``;
+``decode_slots`` updates them in place, one row a slot per layer (see
+``models.transformer.decode_step``), and leaves that layout as it is.
+(The pool pytree carries whatever ``init_decode_state`` defines —
+recurrent SSM/xLSTM states included — but only attention-only archs can
+be *served* through it; see ``engine.pool_supported`` for why MoE and
+recurrent blocks are gated to the legacy fixed-batch path.)
 
 Device-side primitives (pure, jit-friendly, slot index traced so one
 compile covers the pool's whole lifetime):

@@ -124,3 +124,43 @@ def test_matmul_kernels_compile(one_chip, kernel):
                          _sds(one_chip, (4096, 3072), jnp.bfloat16),
                          _sds(one_chip, (3072, 8192), jnp.bfloat16))
     assert "tpu_custom_call" in txt
+
+
+def test_decode_slots_keeps_the_kv_pool_in_place(one_chip, topo):
+    """phi4-mini's decode step at published widths (two layers), 32 pages
+    of 1024: no copy of a whole pool leaf, no dynamic-update-slice writing
+    a layer's slab into a stacked output, temporaries under one leaf."""
+    import dataclasses
+    import re
+
+    from repro.configs import base
+    from repro.launch.mesh import make_mesh
+    from repro.models import transformer as T
+    from repro.serve.engine import ServeConfig, make_serve_fns
+
+    cfg = dataclasses.replace(base.get_config("phi4-mini-3.8b"), n_layers=2)
+    B, S = 32, 1024
+    mesh = make_mesh((1, 1), ("data", "model"), topo.devices[:1])
+    fns = make_serve_fns(cfg, ServeConfig(), mesh, B, S)
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = placed(jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                   jax.random.key(0)))
+    pool = placed(jax.eval_shape(
+        lambda: {**T.init_decode_state(cfg, B, S),
+                 "pos": jnp.zeros((B,), jnp.int32)}))
+    with jax.set_mesh(mesh):
+        compiled = fns.decode_slots.lower(
+            params, pool, _sds(one_chip, (B, 1), jnp.int32),
+            _sds(one_chip, (B,), jnp.int32)).compile()
+    txt = compiled.as_text()
+    leaf = (cfg.n_layers, B, S, cfg.n_kv_heads, cfg.head_dim)
+    dims = ",".join(map(str, leaf))
+    assert not re.search(rf"= bf16\[{dims}\]\S* copy\(", txt)
+    assert not re.search(rf"%\S*dynamic-update-slice\S* = bf16\[{dims}\]",
+                         txt)
+    leaf_bytes = int(np.prod(leaf)) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
